@@ -6,8 +6,8 @@ converter/single-launch rework:
   * single-launch asymmetric-cache decode (one grid over bulk + init +
     local window, in-kernel merge) must beat the legacy bulk-kernel +
     XLA-epilogue path on wall-clock (a Pallas-vs-Pallas comparison, so
-    interpret overhead cancels), with bit-exact outputs at matched
-    tiles,
+    interpret overhead cancels), with outputs that agree within
+    float32 reduction-order noise (twice ``ref.DECODE_ATOL``),
   * the in-kernel FP->BFP converter prefill (the one-launch K+V pair
     kernel feeding the attention kernel, and the single-launch
     prefill-cache region converter) must be bit-exact against the
@@ -192,8 +192,9 @@ def bench_decode(rng, B, Hkv, S, hd=64, n=3):
 
 def bench_decode_single_launch(rng, B, Hkv, S, hd=64, rep=2, n=6):
     """Single-launch asymmetric-cache decode vs the legacy bulk-kernel +
-    XLA-epilogue path, on a real packed cache (jitted; bit-exact at
-    matched bulk tiles).  The two paths are timed *interleaved* (min of
+    XLA-epilogue path, on a real packed cache (jitted; outputs agree
+    within twice ``ref.DECODE_ATOL``:
+    each is within it of the dense oracle).  The two paths are timed *interleaved* (min of
     alternating reps) so a drifting machine load cannot flip the gate's
     sign the way back-to-back min-of-reps can."""
     H = Hkv * rep
@@ -209,7 +210,7 @@ def bench_decode_single_launch(rng, B, Hkv, S, hd=64, rep=2, n=6):
     o_l = legacy_fn(q, cache)                              # compile both
     o_f = fused_fn(q, cache)
     jax.block_until_ready((o_l, o_f))
-    exact = bool(jnp.all(o_l == o_f))
+    max_diff = float(jnp.abs(o_l - o_f).max())
     legacy_s = fused_s = float("inf")
     for _ in range(n):
         t0 = time.time()
@@ -221,11 +222,11 @@ def bench_decode_single_launch(rng, B, Hkv, S, hd=64, rep=2, n=6):
     legacy_us, fused_us = legacy_s * 1e6, fused_s * 1e6
     rec = {"B": B, "Hkv": Hkv, "rep": rep, "S": S, "hd": hd,
            "legacy_us": round(legacy_us, 1), "fused_us": round(fused_us, 1),
-           "speedup": round(legacy_us / fused_us, 2), "bit_exact": exact}
+           "speedup": round(legacy_us / fused_us, 2), "max_abs_diff": max_diff}
     csv(f"kernels.decode_single_launch.B{B}.Hkv{Hkv}.S{S}", fused_us,
         f"legacy_us={legacy_us:.0f},speedup={rec['speedup']},"
-        f"bit_exact={exact}")
-    assert exact, rec
+        f"max_abs_diff={max_diff:.2e}")
+    assert max_diff <= 2 * ref.DECODE_ATOL, rec
     return rec
 
 

@@ -67,15 +67,34 @@ class BfpConfig:
         return self.mantissa_bits + 5.0 / self.group_size
 
 
-def _shared_exponent(group_absmax: jax.Array) -> jax.Array:
-    """floor(log2(absmax)) clipped to the 5-bit FP16 exponent range.
+def pow2(e) -> jax.Array:
+    """Exact float32 ``2**e`` for integer-valued ``e`` in [-126, 127].
 
-    Zero groups get EXP_MIN so their mantissas quantize to exactly zero.
+    Built from the exponent bits.  ``jnp.exp2`` is approximate on XLA
+    CPU (``exp2(-18.0) != 2**-18``), so a step made with it is not a
+    power of two and a quantized value no longer sits on the grid it
+    was quantized to."""
+    e = jnp.asarray(e).astype(jnp.int32)
+    return jax.lax.bitcast_convert_type((e + 127) << 23, jnp.float32)
+
+
+def shared_exponent(group_absmax: jax.Array) -> jax.Array:
+    """floor(log2(absmax)) clipped to the 5-bit FP16 exponent range, as
+    float32.
+
+    The exponent is read from the float32 bit pattern, not computed with
+    ``log2``: XLA's ``log2`` is approximate (``log2(8192)`` returns
+    12.999999 on CPU), which put exact powers of two one bucket low.  The
+    biased-exponent field is exact for every normal float; subnormals
+    read as -127 and clip to EXP_MIN like any value below 2^-14.  Zero
+    groups get EXP_MIN so their mantissas quantize to exactly zero.  The
+    XLA path and the Pallas kernels share this one helper.
     """
-    safe = jnp.where(group_absmax > 0, group_absmax, 1.0)
-    e = jnp.floor(jnp.log2(safe.astype(jnp.float32)))
-    e = jnp.where(group_absmax > 0, e, float(EXP_MIN))
-    return jnp.clip(e, EXP_MIN, EXP_MAX)
+    bits = jax.lax.bitcast_convert_type(
+        group_absmax.astype(jnp.float32), jnp.int32)
+    e = ((bits >> 23) & 0xFF) - 127
+    e = jnp.where(group_absmax > 0, e, EXP_MIN)
+    return jnp.clip(e, EXP_MIN, EXP_MAX).astype(jnp.float32)
 
 
 def _group_reshape(x: jax.Array, group_size: int, axis: int):
@@ -105,8 +124,8 @@ def _quantize_grouped(grouped: jax.Array, cfg: BfpConfig):
     """
     m = cfg.mantissa_bits
     absmax = jnp.max(jnp.abs(grouped), axis=-1)
-    e = _shared_exponent(absmax)  # (..., n_groups) float32
-    step = jnp.exp2(e - (m - 2))[..., None].astype(jnp.float32)
+    e = shared_exponent(absmax)  # (..., n_groups) float32
+    step = pow2(e - (m - 2))[..., None]
     scaled = grouped.astype(jnp.float32) / step
     if cfg.rounding == "trunc":
         mant = jnp.trunc(scaled)
@@ -120,7 +139,7 @@ def _quantize_grouped(grouped: jax.Array, cfg: BfpConfig):
 def _dequantize_grouped(mant: jax.Array, exp: jax.Array,
                         cfg: BfpConfig) -> jax.Array:
     m = cfg.mantissa_bits
-    step = jnp.exp2(exp.astype(jnp.float32) - (m - 2))[..., None]
+    step = pow2(exp.astype(jnp.int32) - (m - 2))[..., None]
     return mant.astype(jnp.float32) * step
 
 

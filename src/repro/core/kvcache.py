@@ -180,7 +180,7 @@ def _q_k(x, bits):
 
 def _dq_k(mant, exp, bits, dtype=jnp.float32):
     g = mant.reshape(mant.shape[:-1] + (mant.shape[-1] // GROUP, GROUP))
-    step = jnp.exp2(exp.astype(jnp.float32) - (bits - 2))[..., None]
+    step = bfp.pow2(exp.astype(jnp.float32) - (bits - 2))[..., None]
     return (g.astype(jnp.float32) * step).reshape(mant.shape).astype(dtype)
 
 
@@ -201,7 +201,7 @@ def _q_v_group(x, bits):
 def _dq_v_group(mant, exp, bits, dtype=jnp.float32):
     B, T, H, D = mant.shape
     g = mant.reshape(B, T // GROUP, GROUP, H, D).astype(jnp.float32)
-    step = jnp.exp2(exp.astype(jnp.float32) - (bits - 2))[:, :, None]
+    step = bfp.pow2(exp.astype(jnp.float32) - (bits - 2))[:, :, None]
     return (g * step).reshape(B, T, H, D).astype(dtype)
 
 

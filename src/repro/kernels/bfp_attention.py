@@ -47,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.bfp import pow2, shared_exponent
+
 GROUP = 32
 NEG_INF = -1e30
 
@@ -63,7 +65,7 @@ BLOCK_S_DECODE = 512
 def _dq_k_tile(k_mant, k_exp, mantissa_bits):
     """(bs, hd) int8 + (bs, hd/32) -> (bs, hd) f32 (per-token groups)."""
     bs, hd = k_mant.shape
-    step = jnp.exp2(k_exp.astype(jnp.float32) - (mantissa_bits - 2))
+    step = pow2(k_exp.astype(jnp.float32) - (mantissa_bits - 2))
     return (k_mant.astype(jnp.float32).reshape(bs, hd // GROUP, GROUP)
             * step[..., None]).reshape(bs, hd)
 
@@ -71,7 +73,7 @@ def _dq_k_tile(k_mant, k_exp, mantissa_bits):
 def _dq_v_tile(v_mant, v_exp, mantissa_bits):
     """(bs, hd) int8 + (bs/32, hd) -> (bs, hd) f32 (token groups)."""
     bs, hd = v_mant.shape
-    step = jnp.exp2(v_exp.astype(jnp.float32) - (mantissa_bits - 2))
+    step = pow2(v_exp.astype(jnp.float32) - (mantissa_bits - 2))
     return (v_mant.astype(jnp.float32).reshape(bs // GROUP, GROUP, hd)
             * step[:, None, :]).reshape(bs, hd)
 
@@ -84,7 +86,7 @@ def _dq_k4_tile(km, ke, hd):
     lo = jnp.where(lo >= 8, lo - 16, lo)
     hi = jnp.where(hi >= 8, hi - 16, hi)
     k_int = jnp.stack([lo, hi], axis=-1).reshape(km.shape[0], hd)
-    kstep = jnp.exp2(ke.astype(jnp.float32) - 2.0)  # m=4
+    kstep = pow2(ke.astype(jnp.float32) - 2.0)  # m=4
     return (k_int.astype(jnp.float32).reshape(-1, hd // GROUP, GROUP)
             * kstep[..., None]).reshape(-1, hd)
 
@@ -97,7 +99,7 @@ def _dq_v4_tile(vm, ve, hd):
     vlo = jnp.where(vlo >= 8, vlo - 16, vlo)
     vhi = jnp.where(vhi >= 8, vhi - 16, vhi)
     v_int = jnp.stack([vlo, vhi], axis=1).reshape(-1, hd)
-    vstep = jnp.exp2(ve.astype(jnp.float32) - 2.0)  # (bs/32, hd)
+    vstep = pow2(ve.astype(jnp.float32) - 2.0)  # (bs/32, hd)
     return (v_int.astype(jnp.float32).reshape(-1, GROUP, hd)
             * vstep[:, None, :]).reshape(-1, hd)
 
@@ -610,7 +612,6 @@ def bfp_attention_decode_batched(q, k_mant4, k_exp, v_mant4, v_exp,
 
 # canonical cache-layout / shared-exponent parameters — the decode
 # kernel must index exactly the regions the cache writes
-from repro.core.bfp import EXP_MAX, EXP_MIN  # noqa: E402
 from repro.core.kvcache import (INIT_TOKENS, LOCAL_TOKENS,  # noqa: E402
                                 V_LOCAL_GROUPS as V_LOCAL_GROUPS_K)
 
@@ -621,7 +622,7 @@ def _dq_k8_batched(mant, exp):
     shp = mant.shape
     g = mant.astype(jnp.float32).reshape(shp[:-1] + (shp[-1] // GROUP,
                                                      GROUP))
-    step = jnp.exp2(exp.astype(jnp.float32) - 6.0)[..., None]
+    step = pow2(exp.astype(jnp.float32) - 6.0)[..., None]
     return (g * step).reshape(shp)
 
 
@@ -636,7 +637,7 @@ def _dq_k4_batched(packed, exp, hd):
     m = jnp.stack([lo, hi], axis=-1).reshape(packed.shape[:-1] + (hd,))
     g = m.astype(jnp.float32).reshape(packed.shape[:-1] + (hd // GROUP,
                                                            GROUP))
-    step = jnp.exp2(exp.astype(jnp.float32) - 2.0)[..., None]
+    step = pow2(exp.astype(jnp.float32) - 2.0)[..., None]
     return (g * step).reshape(packed.shape[:-1] + (hd,))
 
 
@@ -658,10 +659,8 @@ def _decode_asym_kernel(pf, qb_ref, q_ref, kbm_ref, kbe_ref, vbm_ref,
 
     # ---- bulk tiles: one grid step covers ALL kv heads of a batch row
     # (Hkv× fewer steps than the per-(b,h) legacy grid).  The dequant
-    # and flash updates are vectorized over heads (elementwise / per-row
-    # reductions — bitwise equal to per-head), while the QK and PV
-    # contractions stay per-head dots of the legacy kernel's exact
-    # shapes, so each head's flash triple is bitwise the legacy one ----
+    # is vectorized over heads; the QK and PV contractions are per-head
+    # dots ----
     start_abs = pf[3 + jnp.minimum(b, n_b - 1)]
     start = jnp.maximum(start_abs - INIT_TOKENS, 0)
     live = (t < n_b * n_s) & (j * block_s < valid_len) \
@@ -677,7 +676,7 @@ def _decode_asym_kernel(pf, qb_ref, q_ref, kbm_ref, kbe_ref, vbm_ref,
         lo = jnp.where(lo >= 8, lo - 16, lo)
         hi = jnp.where(hi >= 8, hi - 16, hi)
         k_int = jnp.stack([lo, hi], axis=-1).reshape(block_s, n_kv, hd)
-        kstep = jnp.exp2(kbe_ref[0].astype(jnp.float32) - 2.0)
+        kstep = pow2(kbe_ref[0].astype(jnp.float32) - 2.0)
         k = (k_int.astype(jnp.float32)
              .reshape(block_s, n_kv, hd // GROUP, GROUP)
              * kstep[..., None]).reshape(block_s, n_kv, hd)
@@ -687,22 +686,15 @@ def _decode_asym_kernel(pf, qb_ref, q_ref, kbm_ref, kbe_ref, vbm_ref,
         vlo = jnp.where(vlo >= 8, vlo - 16, vlo)
         vhi = jnp.where(vhi >= 8, vhi - 16, vhi)
         v_int = jnp.stack([vlo, vhi], axis=1).reshape(block_s, n_kv, hd)
-        vstep = jnp.exp2(vbe_ref[0].astype(jnp.float32) - 2.0)
+        vstep = pow2(vbe_ref[0].astype(jnp.float32) - 2.0)
         v = (v_int.astype(jnp.float32)
              .reshape(block_s // GROUP, GROUP, n_kv, hd)
              * vstep[:, None]).reshape(block_s, n_kv, hd)
 
-        # per-head flash updates on the legacy kernel's exact (rep, bs)
-        # shapes — shared-exponent dequant batches fine (elementwise ==
-        # bitwise), but the dot contractions and the exp/sum/accumulate
-        # chain must keep their per-head shapes and fusion structure to
-        # reproduce the legacy triples bit-for-bit.  The barrier pins
-        # each head's contraction as its own dot instruction (XLA CPU's
-        # dot-merger would otherwise batch them into one dot_general
-        # with a different f32 reduction order); values are untouched —
-        # it only fences fusion.
+        # per-head flash updates on (rep, bs) tiles, each head's triple
+        # in its own scratch slab
         for h in range(n_kv):
-            s = jnp.dot(*jax.lax.optimization_barrier((q3[h], k[:, h].T)),
+            s = jnp.dot(q3[h], k[:, h].T,
                         preferred_element_type=jnp.float32) \
                 / jnp.sqrt(float(hd))                  # (rep, bs)
             if logit_cap > 0:
@@ -720,14 +712,12 @@ def _decode_asym_kernel(pf, qb_ref, q_ref, kbm_ref, kbe_ref, vbm_ref,
             l_ref[slab] = l_ref[slab] * corr \
                 + jnp.sum(p, axis=-1, keepdims=True)
             acc_ref[slab] = acc_ref[slab] * corr + jnp.dot(
-                *jax.lax.optimization_barrier((p, v[:, h])),
-                preferred_element_type=jnp.float32)
+                p, v[:, h], preferred_element_type=jnp.float32)
             m_ref[slab] = m_new
 
     # ---- final grid step: the 8-bit init block + recent window for
     # *all* (batch, head) at once — one vectorized tile body instead of
-    # the per-step XLA epilogue, mirroring its batched einsum
-    # formulation op-for-op so the merged output is bit-exact ----
+    # the per-step XLA epilogue, in its batched einsum formulation ----
     @pl.when(t == n_b * n_s)
     def _epilogue():
         L = pf[0]
@@ -761,23 +751,19 @@ def _decode_asym_kernel(pf, qb_ref, q_ref, kbm_ref, kbe_ref, vbm_ref,
 
         # V: init group + groups {a0, a0+1, a0+2} from the 8-bit group
         # ring / the residual group re-converted at its current size
-        vie = jnp.exp2(vie_ref[...].astype(jnp.float32) - 6.0)
+        vie = pow2(vie_ref[...].astype(jnp.float32) - 6.0)
         v_init = vim_ref[...].astype(jnp.float32).reshape(
             B, 1, GROUP, n_kv, hd) * vie[:, :, None]
         v_init = v_init.reshape(B, GROUP, n_kv, hd)
-        vle = jnp.exp2(vle_ref[...].astype(jnp.float32) - 6.0)
+        vle = pow2(vle_ref[...].astype(jnp.float32) - 6.0)
         v_loc = vlm_ref[...].astype(jnp.float32)
         ring0 = v_loc[:, :GROUP] * vle[:, 0:1]
         ring1 = v_loc[:, GROUP:] * vle[:, 1:2]
         resid_raw = vr_ref[...].astype(jnp.float32)    # (B, 32, Hkv, hd)
         tok32 = jax.lax.broadcasted_iota(jnp.int32, (GROUP, 1), 0)[:, 0]
         resid = jnp.where((tok32 < r)[None, :, None, None], resid_raw, 0.0)
-        absmax = jnp.max(jnp.abs(resid), axis=1)       # (B, Hkv, hd)
-        safe = jnp.where(absmax > 0, absmax, 1.0)
-        e = jnp.floor(jnp.log2(safe))
-        e = jnp.where(absmax > 0, e, float(EXP_MIN))
-        e = jnp.clip(e, EXP_MIN, EXP_MAX)
-        step = jnp.exp2(e - 6.0)[:, None]
+        e = shared_exponent(jnp.max(jnp.abs(resid), axis=1))  # (B,Hkv,hd)
+        step = pow2(e - 6.0)[:, None]
         resid_q = jnp.clip(jnp.trunc(resid / step), -127.0, 127.0) * step
         a0 = jnp.maximum(cg - 2, 1)
         parts = []
@@ -793,10 +779,7 @@ def _decode_asym_kernel(pf, qb_ref, q_ref, kbm_ref, kbe_ref, vbm_ref,
         valid_ep = (pos_ep[None, :] < L) \
             & (pos_ep[None, :] >= starts[:, None])     # (B, 128)
 
-        # scores/softmax/PV with the legacy epilogue's exact einsum
-        # shapes — batch dims (b, g) — so the contraction order matches
-        # the XLA formulation bitwise at every rep (incl. the rep=1
-        # GEMV, where a per-head dot would reduce in a different order)
+        # scores/softmax/PV as batched einsums over (b, g)
         qg = q5.reshape(B, 1, n_kv, rep, hd)
         s_e = jnp.einsum("bsgrd,btgd->bgrst", qg, k_ep,
                          preferred_element_type=jnp.float32) \
@@ -840,10 +823,9 @@ def bfp_attention_decode_asym_batched(q, k_bulk_mant, k_bulk_exp,
     One ``pallas_call`` over a flattened grid of B·(S_bulk/bs) + 1
     steps: the bulk sweep walks the 4-bit nibble-packed region with one
     step per batch row covering all kv heads (Hkv× fewer grid steps
-    than the per-(b,h) legacy grid; dequant and flash updates vectorized
-    over heads, QK/PV contractions kept as per-head dots of the legacy
-    shapes, each head's flash triple in its own scratch slab — bitwise
-    the legacy triple, same dead-tile skip rule),
+    than the per-(b,h) legacy grid; dequant vectorized over heads,
+    QK/PV contractions as per-head dots, each head's flash triple in its
+    own scratch slab, same dead-tile skip rule),
     and the *single* final step dequantizes the three small 8-bit
     regions for every (batch, head) at once (init block, local K ring
     rolled into position order via a 2-phase select, the ≤32 freshly
@@ -853,10 +835,8 @@ def bfp_attention_decode_asym_batched(q, k_bulk_mant, k_bulk_exp,
     extra launches and the XLA dynamic-slice/select epilogue per layer
     per step.  ``v_bulk_exp`` is indexed directly (bulk-relative layout:
     slot j = group j+1) — no per-step exponent shift exists on this
-    path.  The final step mirrors the legacy XLA epilogue's batched
-    einsum formulation op-for-op, which is what makes the merged output
-    bit-exact against the kernel+epilogue path at matched bulk tiles
-    (both jitted) at every GQA rep, including the rep=1 GEMV shape.
+    path.  Tests hold it to ``kernels/ref.py``'s dense decode under a
+    float32 tolerance.
 
     q: (B, H, hd); cache regions in their ``AsymKVCache`` layouts;
     length: () int32 cache length; start: optional (B,) int32 left-pad

@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.core.bfp import pow2
+
 GROUP_A = 32
 GROUP_W = 128
 
@@ -55,8 +57,8 @@ def _dequant_tiles(a_mant_ref, a_exp_ref, w_packed_ref, w_scale_ref,
     """Dequantize the VMEM-resident operand tiles to f32."""
     a_m = a_mant_ref[...].astype(jnp.float32)        # (bm, bk)
     bm, bk = a_m.shape
-    step = jnp.exp2(a_exp_ref[...].astype(jnp.float32)
-                    - (mantissa_bits - 2))           # (bm, bk/32)
+    step = pow2(a_exp_ref[...].astype(jnp.int32)
+                - (mantissa_bits - 2))               # (bm, bk/32)
     a = (a_m.reshape(bm, bk // GROUP_A, GROUP_A)
          * step[..., None]).reshape(bm, bk)
 
@@ -115,8 +117,8 @@ def _mm_int_kernel(a_mant_ref, a_exp_ref, w_packed_ref, w_scale_ref,
         a_g.astype(jnp.float32), w_g.astype(jnp.float32),
         (((2,), (1,)), ((1,), (0,))),
         preferred_element_type=jnp.float32)          # (nga, bm, bn)
-    a_step = jnp.exp2(a_exp_ref[...].astype(jnp.float32)
-                      - (mantissa_bits - 2))         # (bm, nga)
+    a_step = pow2(a_exp_ref[...].astype(jnp.int32)
+                  - (mantissa_bits - 2))             # (bm, nga)
     ws = w_scale_ref[...]                            # (K/128, bn)
     ws_g = jnp.repeat(ws, GROUP_W // GROUP_A, axis=0)  # (nga, bn)
     acc = jnp.sum(pp * a_step.T[:, :, None] * ws_g[:, None, :], axis=0)

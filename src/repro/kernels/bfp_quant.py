@@ -33,24 +33,15 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.bfp import EXP_MAX, EXP_MIN
+from repro.core.bfp import pow2, shared_exponent
 
 GROUP = 32
-
-
-def _shared_exp(absmax):
-    """floor(log2(absmax)) clipped to [-14, 15]; zero groups -> EXP_MIN.
-    Mirrors ``bfp._shared_exponent`` op-for-op (bit-exact)."""
-    safe = jnp.where(absmax > 0, absmax, 1.0)
-    e = jnp.floor(jnp.log2(safe))
-    e = jnp.where(absmax > 0, e, float(EXP_MIN))
-    return jnp.clip(e, EXP_MIN, EXP_MAX)
 
 
 def _mantissa(g, e, mantissa_bits: int, rounding: str = "trunc"):
     """g: (..., n_groups, GROUP) fp32 with exps e (..., n_groups) -> f32
     mantissa values in [-(2^(m-1)-1), 2^(m-1)-1]."""
-    step = jnp.exp2(e - (mantissa_bits - 2))
+    step = pow2(e - (mantissa_bits - 2))
     scaled = g / step[..., None]
     m = jnp.trunc(scaled) if rounding == "trunc" else jnp.round(scaled)
     lim = float(2 ** (mantissa_bits - 1) - 1)
@@ -72,7 +63,7 @@ def _quant_kernel(x_ref, mant_ref, exp_ref, *, mantissa_bits: int,
     x = x_ref[...].astype(jnp.float32)                 # (bm, bk)
     bm, bk = x.shape
     g = x.reshape(bm, bk // GROUP, GROUP)
-    e = _shared_exp(jnp.max(jnp.abs(g), axis=-1))      # (bm, bk/32)
+    e = shared_exponent(jnp.max(jnp.abs(g), axis=-1))  # (bm, bk/32)
     m = _mantissa(g, e, mantissa_bits, rounding)
     mant_ref[...] = m.reshape(bm, bk).astype(jnp.int8)
     exp_ref[...] = e.astype(jnp.int8)
@@ -131,7 +122,7 @@ def _quant_kv_batched_kernel(x_ref, mant_ref, exp_ref, *, mantissa_bits,
     x = x_ref[0, :, 0].astype(jnp.float32)             # (bs, hd)
     bs, hd = x.shape
     g = x.reshape(bs, hd // GROUP, GROUP)
-    e = _shared_exp(jnp.max(jnp.abs(g), axis=-1))      # (bs, hd/32)
+    e = shared_exponent(jnp.max(jnp.abs(g), axis=-1))  # (bs, hd/32)
     m = _mantissa(g, e, mantissa_bits, rounding).reshape(bs, hd)
     if pack:
         mant_ref[0, :, 0] = _pack_nibbles(m, axis=-1)
@@ -185,7 +176,7 @@ def _quant_v_batched_kernel(x_ref, mant_ref, exp_ref, *, mantissa_bits,
     x = x_ref[0, :, 0].astype(jnp.float32)             # (bs, hd)
     bs, hd = x.shape
     g = jnp.moveaxis(x.reshape(bs // GROUP, GROUP, hd), 1, 2)
-    e = _shared_exp(jnp.max(jnp.abs(g), axis=-1))      # (bs/32, hd)
+    e = shared_exponent(jnp.max(jnp.abs(g), axis=-1))  # (bs/32, hd)
     m = _mantissa(g, e, mantissa_bits, rounding)       # (bs/32, hd, 32)
     m = jnp.moveaxis(m, 2, 1).reshape(bs, hd)
     if pack:
@@ -310,7 +301,7 @@ def _prefill_cache_kernel(k_ref, v_ref, off_ref,
     # ---- K: one shared-exponent reduction feeds the 8b and 4b paths ----
     k = k_ref[0, :, 0].astype(jnp.float32) - off_ref[0, 0][None, :]
     kg = k.reshape(S, hd // GROUP, GROUP)
-    ke = _shared_exp(jnp.max(jnp.abs(kg), axis=-1))    # (S, hd/32)
+    ke = shared_exponent(jnp.max(jnp.abs(kg), axis=-1))  # (S, hd/32)
     km8 = _mantissa(kg, ke, 8).reshape(S, hd)
 
     kim_ref[0, :, 0] = km8[:INIT_TOKENS].astype(i8)
@@ -355,7 +346,7 @@ def _prefill_cache_kernel(k_ref, v_ref, off_ref,
     # ---- V: token groups, again one exponent reduction for both widths ----
     v = v_ref[0, :, 0].astype(jnp.float32)
     vg = jnp.moveaxis(v.reshape(cg, GROUP, hd), 1, 2)  # (cg, hd, 32)
-    ve = _shared_exp(jnp.max(jnp.abs(vg), axis=-1))    # (cg, hd)
+    ve = shared_exponent(jnp.max(jnp.abs(vg), axis=-1))  # (cg, hd)
     vm8 = jnp.moveaxis(_mantissa(vg, ve, 8), 2, 1)     # (cg, 32, hd)
 
     vim_ref[0, :, 0] = vm8[0].astype(i8)

@@ -1,8 +1,8 @@
 """Jitted public wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU so the same call sites run
-everywhere (CPU CI validates kernel numerics; TPU compiles the real
-Mosaic kernels).
+``interpret`` defaults to True on the CPU backend (tests validate kernel
+numerics in the interpreter) and False on a TPU (real Mosaic kernels);
+any other backend raises.
 
 The attention wrappers default to the grid-fused batched kernels
 (one ``pallas_call`` over the (batch × kv-head) grid, zero layout
@@ -42,7 +42,14 @@ LEGACY_BLOCK_S = 128
 
 
 def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Mosaic on a TPU, the Pallas interpreter on the CPU (tests), and
+    an error anywhere else: these kernels are written for the TPU, and
+    interpreting them on another accelerator would hide that."""
+    backend = jax.default_backend()
+    if backend in ("tpu", "cpu"):
+        return backend == "cpu"
+    raise RuntimeError(f"Pallas kernels need a TPU (or the CPU "
+                       f"interpreter); backend is {backend!r}")
 
 
 @partial(jax.jit, static_argnames=("mantissa_bits", "rounding", "interpret"))

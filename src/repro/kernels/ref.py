@@ -23,7 +23,7 @@ def dequant_act(a_mant: jax.Array, a_exp: jax.Array,
     """(M, K) int8 + (M, K/32) int8 -> (M, K) f32."""
     M, K = a_mant.shape
     g = a_mant.reshape(M, K // GROUP_A, GROUP_A).astype(jnp.float32)
-    step = jnp.exp2(a_exp.astype(jnp.float32) - (mantissa_bits - 2))
+    step = bfp.pow2(a_exp.astype(jnp.float32) - (mantissa_bits - 2))
     return (g * step[..., None]).reshape(M, K)
 
 
@@ -65,7 +65,7 @@ def ref_bfp_matmul_int(a_mant, a_exp, w_packed, w_scale,
     w_g = w_int.reshape(nga, GROUP_A, N)
     # int dot per group -> (M, nga, N)
     pp = jnp.einsum("mgk,gkn->mgn", a_g, w_g).astype(jnp.float32)
-    a_step = jnp.exp2(a_exp.astype(jnp.float32) - (mantissa_bits - 2))
+    a_step = bfp.pow2(a_exp.astype(jnp.float32) - (mantissa_bits - 2))
     rep = GROUP_W // GROUP_A
     ws = jnp.repeat(w_scale, rep, axis=0)            # (nga, N)
     return jnp.einsum("mgn,mg,gn->mn", pp, a_step, ws).astype(out_dtype)
@@ -82,7 +82,7 @@ def ref_bfp_attention_prefill(q, k_mant, k_exp, v_mant, v_exp, *,
     S, hd = q.shape
     k = dequant_act(k_mant, k_exp, mantissa_bits)            # (S, hd)
     vg = v_mant.reshape(S // GROUP_A, GROUP_A, hd).astype(jnp.float32)
-    vstep = jnp.exp2(v_exp.astype(jnp.float32) - (mantissa_bits - 2))
+    vstep = bfp.pow2(v_exp.astype(jnp.float32) - (mantissa_bits - 2))
     v = (vg * vstep[:, None, :]).reshape(S, hd)
 
     s = (q.astype(jnp.float32) @ k.T) / jnp.sqrt(float(hd))
@@ -110,12 +110,12 @@ def ref_bfp_decode_bulk(q, k_mant4, k_exp, v_mant4, v_exp,
     S2 = k_mant4.shape[0]
     hd = q.shape[-1]
     k_int = bfp.unpack_int4(k_mant4, axis=-1).astype(jnp.float32)
-    kstep = jnp.exp2(k_exp.astype(jnp.float32) - 2.0)        # m=4
+    kstep = bfp.pow2(k_exp.astype(jnp.float32) - 2.0)        # m=4
     k = (k_int.reshape(S2, hd // GROUP_A, GROUP_A)
          * kstep[..., None]).reshape(S2, hd)
     v_int = bfp.unpack_int4(v_mant4, axis=0).astype(jnp.float32)  # (S, hd)
     S = v_int.shape[0]
-    vstep = jnp.exp2(v_exp.astype(jnp.float32) - 2.0)        # (S/32, hd)
+    vstep = bfp.pow2(v_exp.astype(jnp.float32) - 2.0)        # (S/32, hd)
     v = (v_int.reshape(S // GROUP_A, GROUP_A, hd)
          * vstep[:, None, :]).reshape(S, hd)
 
@@ -130,7 +130,41 @@ def ref_bfp_decode_bulk(q, k_mant4, k_exp, v_mant4, v_exp,
     return o, m, l
 
 
+# Decode outputs are O(1) softmax averages of O(1) values over at most a
+# few thousand tokens; a decode path differs from ``ref_attention_decode``
+# only in f32 reduction order (<= 2.4e-7 measured at 512 tokens on CPU),
+# so 2e-6 (~16 ulp at 1.0) tells reduction order from a defect (a wrong
+# mask or region is off by ~1e-2).
+DECODE_ATOL = 2e-6
+
+
+def ref_attention_decode(q, k, v, valid, *, logit_cap: float = 0.0,
+                         start=None):
+    """Dense one-token GQA decode oracle, all in float32.
+
+    q: (B, H, hd); k, v: (B, S, Hkv, hd) dequantized cache in position
+    order (``kvcache.gather_kv``); valid: (S,) bool; start: optional (B,)
+    left-pad count — positions below it are masked.  Returns normalized
+    (B, H, hd)."""
+    B, H, hd = q.shape
+    Hkv = k.shape[2]
+    qg = q.astype(jnp.float32).reshape(B, Hkv, H // Hkv, hd)
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bgrd,btgd->bgrt", qg, k.astype(jnp.float32))
+        s = s / jnp.sqrt(float(hd))
+        if logit_cap > 0:
+            s = logit_cap * jnp.tanh(s / logit_cap)
+        mask = jnp.broadcast_to(valid[None], (B, k.shape[1]))
+        if start is not None:
+            mask = mask & (jnp.arange(k.shape[1])[None] >= start[:, None])
+        s = jnp.where(mask[:, None, None], s, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        o = jnp.einsum("bgrt,btgd->bgrd", p, v.astype(jnp.float32))
+    return o.reshape(B, H, hd)
+
+
 __all__ = ["dequant_act", "dequant_weight", "ref_bfp_quantize",
            "ref_bfp_matmul", "ref_bfp_matmul_int",
            "ref_bfp_attention_prefill", "ref_bfp_decode_bulk",
+           "ref_attention_decode", "DECODE_ATOL",
            "GROUP_A", "GROUP_W"]

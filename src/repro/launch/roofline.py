@@ -1,7 +1,8 @@
-"""Roofline analysis over the dry-run artifacts (EXPERIMENTS.md §Roofline).
+"""Roofline analysis over the dry-run artifacts.
 
-Hardware constants (TPU v5e): 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI.
+Peak rates come from ``PEAKS``, keyed by the ``device_kind`` JAX reports
+for the target chip (``--device-kind``); a kind missing from the table is
+an error, never a default.
 
 Per (arch x shape x mesh) cell:
   compute term    = HLO_FLOPs_per_chip / peak_FLOPs
@@ -19,7 +20,7 @@ Per (arch x shape x mesh) cell:
 
 Usage:
   PYTHONPATH=src python -m repro.launch.roofline --dir experiments/dryrun \
-      [--md experiments/roofline.md]
+      --device-kind "TPU v5 lite" [--md experiments/roofline.md]
 """
 from __future__ import annotations
 
@@ -28,9 +29,20 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+# Per-chip peaks by ``jax.Device.device_kind``.  Source: Google Cloud
+# documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+# of inter-chip interconnect = 200 GB/s per chip).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 200e9},
+}
+
+
+def peaks(device_kind: str) -> dict:
+    """Peak rates of one chip of ``device_kind``; unknown kinds raise."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak rates for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 
 def model_flops(rec: dict) -> float:
@@ -44,17 +56,18 @@ def model_flops(rec: dict) -> float:
     return 2.0 * n_active * B  # decode: one token per row
 
 
-def analyze(rec: dict) -> dict:
+def analyze(rec: dict, device_kind: str) -> dict:
+    peak = peaks(device_kind)
     mesh = rec["mesh"]
     chips = 1
     for v in mesh.values():
         chips *= v
     c = rec["cost_analysis"]
-    compute_t = c["flops"] / PEAK_FLOPS
-    memory_t = c["bytes_accessed"] / HBM_BW
-    coll_t = c["collectives"]["total_bytes"] / LINK_BW
+    compute_t = c["flops"] / peak["flops"]
+    memory_t = c["bytes_accessed"] / peak["hbm_bw"]
+    coll_t = c["collectives"]["total_bytes"] / peak["link_bw"]
     mf = model_flops(rec)
-    ideal_t = mf / chips / PEAK_FLOPS
+    ideal_t = mf / chips / peak["flops"]
     step_t = max(compute_t, memory_t, coll_t)
     dominant = ["compute", "memory", "collective"][
         [compute_t, memory_t, coll_t].index(step_t)]
@@ -83,7 +96,7 @@ HINTS = {
 }
 
 
-def load_dir(d: str):
+def load_dir(d: str, device_kind: str):
     recs = []
     for f in sorted(glob.glob(os.path.join(d, "*.json"))):
         with open(f) as fh:
@@ -91,7 +104,7 @@ def load_dir(d: str):
         if "error" in r or "skipped" in r:
             recs.append(r)
             continue
-        recs.append({**r, "_analysis": analyze(r)})
+        recs.append({**r, "_analysis": analyze(r, device_kind)})
     return recs
 
 
@@ -148,8 +161,10 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--dir", default="experiments/dryrun")
     ap.add_argument("--md", default="experiments/roofline.md")
+    ap.add_argument("--device-kind", required=True, choices=sorted(PEAKS),
+                    help="target chip, as jax.Device.device_kind names it")
     args = ap.parse_args()
-    recs = load_dir(args.dir)
+    recs = load_dir(args.dir, args.device_kind)
     md = to_markdown(recs)
     print(md)
     targets = pick_hillclimb_targets(recs)

@@ -19,6 +19,11 @@ initializes — the CI / laptop way to exercise a real multi-device mesh:
 
   PYTHONPATH=src python -m repro.launch.serve --smoke \
       --force-host-devices 8 --mesh 2x2
+
+Serving weights are drawn and INT4-packed one layer at a time
+(``init_packed_params``), so the full-precision tree never exists.
+Compiled programs persist in ``$JAX_COMPILATION_CACHE_DIR``, or else in
+``.jax_cache/`` at the root of the checkout (``launch/device.py``).
 """
 from __future__ import annotations
 
@@ -81,9 +86,12 @@ def main():
 
     from repro.configs import get_arch
     from repro.core.quant_config import get_recipe
-    from repro.models.init import init_params
+    from repro.launch.device import enable_compile_cache
+    from repro.models.init import abstract_params, init_packed_params
     from repro.quant.int4 import pack_params
     from repro.serving.engine import Engine, EngineConfig, ServeLoop
+
+    enable_compile_cache()
 
     mesh = None
     if args.mesh is not None:
@@ -95,17 +103,18 @@ def main():
 
     spec = get_arch(args.arch)
     cfg = spec.smoke if args.smoke else spec.config
-    # init directly onto the mesh so serving-scale weights never
-    # materialize unsharded on one device
-    params = init_params(cfg, jax.random.PRNGKey(0), mesh=mesh)
+    restored = None
     if args.ckpt:
         from repro.checkpoint.manager import CheckpointManager
-        mgr = CheckpointManager(args.ckpt)
-        restored = mgr.restore_latest({"params": params})
-        if restored:
-            params = restored[0]["params"]
-            print(f"[serve] restored step {restored[1]}")
-    params = pack_params(params)
+        restored = CheckpointManager(args.ckpt).restore_latest(
+            {"params": abstract_params(cfg)})
+    if restored:
+        print(f"[serve] restored step {restored[1]}")
+        params = pack_params(restored[0]["params"])   # Engine places it
+    else:
+        # drawn and packed layer by layer (straight into the mesh
+        # shardings): the full-precision tree never exists
+        params = init_packed_params(cfg, jax.random.PRNGKey(0), mesh=mesh)
 
     eng = Engine(params, cfg, EngineConfig(
         max_seq=args.max_seq, max_new_tokens=args.max_new,

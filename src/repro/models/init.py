@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
+from repro.quant.int4 import pack_params
 
 
 def _dtype(cfg: ModelConfig):
@@ -164,17 +165,9 @@ def _stack_blocks(key, cfg: ModelConfig, kind: str, count: int,
     return jax.tree.map(lambda *xs: jnp.stack(xs), *blocks)
 
 
-def init_params(cfg: ModelConfig, key: jax.Array, mesh=None) -> Dict:
-    """Full parameter tree.  Use jax.eval_shape(init_params, cfg, key)
-    (with cfg static via partial) for allocation-free dry-runs.
-
-    ``mesh``: optional ``jax.sharding.Mesh`` — the tree is placed
-    according to :func:`repro.distributed.sharding.param_pspecs`
-    (Megatron column/row sharding on the ``model`` axis) instead of
-    living replicated on device 0, so serving-scale models never
-    materialize unsharded."""
+def _top_params(cfg: ModelConfig, ks) -> Dict:
+    """Every leaf outside the stacked blocks: embeddings, head, norms."""
     dt = _dtype(cfg)
-    ks = jax.random.split(key, 8)
     params: Dict = {
         "embed": (jax.random.normal(ks[0], (cfg.vocab_size, cfg.d_model),
                                     jnp.float32) * 0.02).astype(dt),
@@ -182,31 +175,119 @@ def init_params(cfg: ModelConfig, key: jax.Array, mesh=None) -> Dict:
     _norm_params(cfg, "final_norm", params, dt)
     if not cfg.tie_embeddings:
         params["lm_head"] = _dense(ks[1], cfg.d_model, cfg.vocab_size, dt)
-
-    blocks: Dict = {}
-    kind_keys = jax.random.split(ks[2], len(cfg.kind_counts()))
-    for (kind, count), kk in zip(sorted(cfg.kind_counts().items()),
-                                 kind_keys):
-        blocks[kind] = _stack_blocks(kk, cfg, kind, count,
-                                     cross=cfg.cross_attention)
-    params["blocks"] = blocks
-
     if cfg.is_encoder_decoder:
-        enc_cfg = _encoder_view(cfg)
-        params["enc_blocks"] = _stack_blocks(ks[3], enc_cfg, "attn",
-                                             cfg.encoder_layers)
-        _norm_params(enc_cfg, "enc_final_norm", params, dt)
-    if mesh is not None:
-        params = shard_params(params, cfg, mesh)
+        _norm_params(_encoder_view(cfg), "enc_final_norm", params, dt)
     return params
 
 
-def shard_params(params: Dict, cfg: ModelConfig, mesh) -> Dict:
-    """Place a (possibly INT4-packed) param tree on ``mesh`` per
-    ``param_pspecs`` — the serving engine's weight placement."""
-    from repro.distributed.sharding import param_pspecs, to_named
-    return jax.device_put(params,
-                          to_named(param_pspecs(cfg, params, mesh), mesh))
+def _block_stacks(cfg: ModelConfig, ks):
+    """(tree path, block config, kind, count, key, cross) of each stack of
+    blocks — the one place that assigns keys to layers, so the whole-tree
+    and the layer-by-layer initializers draw the same weights."""
+    kinds = sorted(cfg.kind_counts().items())
+    stacks = [(("blocks", kind), cfg, kind, count, kk, cfg.cross_attention)
+              for (kind, count), kk in zip(
+                  kinds, jax.random.split(ks[2], len(kinds)))]
+    if cfg.is_encoder_decoder:
+        stacks.append((("enc_blocks",), _encoder_view(cfg), "attn",
+                       cfg.encoder_layers, ks[3], False))
+    return stacks
+
+
+def _put(tree: Dict, path: tuple, value) -> None:
+    for name in path[:-1]:
+        tree = tree.setdefault(name, {})
+    tree[path[-1]] = value
+
+
+def init_params(cfg: ModelConfig, key: jax.Array) -> Dict:
+    """Full parameter tree (training, tests, accuracy runs).  Use
+    jax.eval_shape(init_params, cfg, key) (with cfg static via partial)
+    for allocation-free dry-runs.  Serving builds its INT4 weights with
+    :func:`init_packed_params`, which never holds this tree."""
+    ks = jax.random.split(key, 8)
+    params = _top_params(cfg, ks)
+    params["blocks"] = {}
+    for path, bcfg, kind, count, kk, cross in _block_stacks(cfg, ks):
+        _put(params, path, _stack_blocks(kk, bcfg, kind, count, cross))
+    return params
+
+
+def _drop_lead(spec):
+    """Per-layer spec of a layer-stacked leaf (the layer axis is never
+    sharded)."""
+    from jax.sharding import PartitionSpec as P
+    if len(spec) and spec[0] is not None:
+        raise ValueError(f"layer axis of a stacked leaf is sharded: {spec}")
+    return P(*tuple(spec)[1:])
+
+
+def init_packed_params(cfg: ModelConfig, key: jax.Array,
+                       mesh=None) -> Dict:
+    """INT4-packed serving weights, drawn and packed one layer at a time.
+
+    Equal, leaf for leaf, to ``pack_params(init_params(cfg, key))``, but
+    the full-precision tree never exists: each layer is drawn from its
+    key and packed by the same op-by-op code as that pair (so the bits
+    agree), then written into its slot of a preallocated stacked buffer
+    (donated, so updated in place) before the next layer is drawn.  At
+    Llama-3.1-8B width the bf16 tree is ~16 GB and the packed one
+    ~5 GB, so this is what lets one 16 GB chip hold the model.
+
+    ``mesh``: the stacked buffers are allocated in their
+    :func:`repro.distributed.sharding.param_pspecs` shardings and each
+    packed layer is placed into its shards as soon as it is packed; only
+    the layer being drawn exists unsharded, on the default device."""
+    from jax.sharding import NamedSharding
+
+    def named(spec):
+        return None if mesh is None else NamedSharding(mesh, spec)
+
+    ks = jax.random.split(key, 8)
+    stacks = _block_stacks(cfg, ks)
+
+    def draw(bcfg, kind, cross, k):
+        return pack_params(init_block(k, bcfg, kind, cross))
+
+    abstract = jax.eval_shape(
+        lambda ks: pack_params(_top_params(cfg, ks)), ks)
+    for path, bcfg, kind, count, kk, cross in stacks:
+        layer = jax.eval_shape(partial(draw, bcfg, kind, cross), kk)
+        _put(abstract, path, jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct((count,) + a.shape, a.dtype),
+            layer))
+    if mesh is None:
+        specs = jax.tree.map(lambda _: None, abstract)
+    else:
+        from repro.distributed.sharding import param_pspecs
+        specs = param_pspecs(cfg, abstract, mesh)
+    is_spec = lambda x: x is None or isinstance(x, jax.sharding.PartitionSpec)
+
+    params = {}
+    for name, leaf in pack_params(_top_params(cfg, ks)).items():
+        params[name] = jax.device_put(leaf, jax.tree.map(
+            named, specs[name], is_leaf=is_spec))
+    for path, bcfg, kind, count, kk, cross in stacks:
+        st_abs, st_spec = abstract, specs
+        for name in path:
+            st_abs, st_spec = st_abs[name], st_spec[name]
+        st_sh = jax.tree.map(named, st_spec, is_leaf=is_spec)
+        layer_sh = jax.tree.map(
+            lambda s: named(None if s is None else _drop_lead(s)), st_spec,
+            is_leaf=is_spec)
+        buf = jax.jit(lambda: jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype), st_abs),
+            out_shardings=st_sh)()
+        write = jax.jit(
+            lambda buf, x, i: jax.tree.map(
+                lambda b, a: jax.lax.dynamic_update_index_in_dim(
+                    b, a, i, 0), buf, x),
+            donate_argnums=0, out_shardings=st_sh)
+        for i, k in enumerate(jax.random.split(kk, count)):
+            layer = jax.device_put(draw(bcfg, kind, cross, k), layer_sh)
+            buf = write(buf, layer, i)
+        _put(params, path, buf)
+    return params
 
 
 def _encoder_view(cfg: ModelConfig) -> ModelConfig:
@@ -222,5 +303,5 @@ def abstract_params(cfg: ModelConfig):
     return jax.eval_shape(partial(init_params, cfg), key)
 
 
-__all__ = ["init_params", "shard_params", "abstract_params", "init_block",
-           "init_mlp", "_encoder_view"]
+__all__ = ["init_params", "init_packed_params", "abstract_params",
+           "init_block", "init_mlp", "_encoder_view"]

@@ -83,15 +83,31 @@ def fake_quant_weight(w: jax.Array, group: int = DEFAULT_GROUP,
     return deq[:orig_in].astype(w.dtype)
 
 
+# Output columns quantize independently, so a weight is packed in blocks
+# of columns holding at most this many elements: run op by op (the
+# serving initializer), a (d_model, vocab) head then needs a block's f32
+# temporaries, not the whole matrix's several times over.
+_QUANT_BLOCK_ELEMS = 1 << 26
+
+
 def quantize_weight(w: jax.Array, group: int = DEFAULT_GROUP,
                     clip: float = 1.0) -> QuantizedWeight:
     """Pack to real INT4 storage (in-dim must be even; group-divisible)."""
     if w.shape[0] % group != 0:
         raise ValueError(f"in_dim {w.shape[0]} not divisible by {group}")
-    q, scales, _ = _quant_deq(w.astype(jnp.float32), group, clip)
-    mant = q.reshape(w.shape).astype(jnp.int8)
-    return QuantizedWeight(packed=bfp.pack_int4(mant, axis=0),
-                           scale=scales.astype(jnp.float32))
+    cols = max(1, _QUANT_BLOCK_ELEMS // w.shape[0])
+    packed, scales = [], []
+    for j in range(0, w.shape[1], cols):
+        blk = w[:, j:j + cols]
+        q, sc = _quant_deq(blk.astype(jnp.float32), group, clip)[:2]
+        packed.append(bfp.pack_int4(q.reshape(blk.shape).astype(jnp.int8),
+                                    axis=0))
+        scales.append(sc.astype(jnp.float32))
+        del blk, q, sc
+    if len(packed) == 1:
+        return QuantizedWeight(packed=packed[0], scale=scales[0])
+    return QuantizedWeight(packed=jnp.concatenate(packed, axis=1),
+                           scale=jnp.concatenate(scales, axis=1))
 
 
 def _is_quantizable(path: tuple, leaf) -> bool:

@@ -91,6 +91,14 @@ def test_padding_of_ragged_axis():
 # same checkers also run under generated inputs (pyproject `test` extra).
 # ---------------------------------------------------------------------------
 
+def _exact_exponent(absmax: float) -> int:
+    """floor(log2(absmax)) clipped, exact: frexp gives absmax = m * 2^e
+    with m in [0.5, 1), so floor(log2) = e - 1 even at powers of two and
+    just below them, where a floating-point log2 can round across."""
+    return int(np.clip(np.frexp(np.float32(absmax))[1] - 1, bfp.EXP_MIN,
+                       bfp.EXP_MAX))
+
+
 def _check_roundtrip_error_bound(x: np.ndarray, m_bits: int):
     """|x - q(x)| <= truncation step derived from the group absmax, and
     the bound tightens with mantissa width."""
@@ -100,10 +108,7 @@ def _check_roundtrip_error_bound(x: np.ndarray, m_bits: int):
     if absmax == 0:
         assert jnp.all(fq == 0)
         return
-    # mirror _shared_exponent's float32 log2: f64 floor(log2) disagrees
-    # by one just below powers of two (e.g. nextafter(2048, 0))
-    E = np.clip(np.floor(np.log2(np.float32(absmax))), bfp.EXP_MIN,
-                bfp.EXP_MAX)
+    E = _exact_exponent(absmax)
     step = 2.0 ** (float(E) - (m_bits - 2))
     assert float(jnp.max(jnp.abs(xj - fq))) <= step * (1 + 1e-5) + 1e-6
 
@@ -119,9 +124,7 @@ def _check_shared_exponent_dominance(x: np.ndarray):
     if absmax == 0:
         assert int(exp.reshape(-1)[0]) == bfp.EXP_MIN
         return
-    # float32 log2, matching the implementation (see error-bound checker)
-    expect = int(np.clip(np.floor(np.log2(np.float32(absmax))),
-                         bfp.EXP_MIN, bfp.EXP_MAX))
+    expect = _exact_exponent(absmax)
     assert int(exp.reshape(-1)[0]) == expect
     step = 2.0 ** (expect - 6)               # 8-bit mantissa step
     fq = np.asarray(bfp.bfp_fake_quant(xj, 32, 8))[0]
@@ -147,6 +150,27 @@ def _check_idempotence(x: np.ndarray, m_bits: int):
 
 
 _BITS = (2, 4, 6, 8)
+
+
+def test_shared_exponent_exact_at_powers_of_two():
+    """The exponent is floor(log2) exactly at 2^e and just below it, and
+    the quantizer's steps are exact powers of two, so a block whose
+    absmax is a power of two is a fixed point (the saved Hypothesis
+    example: m=3 with an 8193 outlier)."""
+    e = np.arange(bfp.EXP_MIN, bfp.EXP_MAX + 1)
+    at = np.float32(2.0) ** e.astype(np.float32)
+    below = np.nextafter(at, np.float32(0))
+    np.testing.assert_array_equal(
+        np.asarray(bfp.shared_exponent(jnp.asarray(at))), e)
+    np.testing.assert_array_equal(
+        np.asarray(bfp.shared_exponent(jnp.asarray(below))),
+        np.maximum(e - 1, bfp.EXP_MIN))
+    k = np.arange(-40, 40)
+    np.testing.assert_array_equal(np.asarray(bfp.pow2(jnp.asarray(k))),
+                                  np.float32(2.0) ** k.astype(np.float32))
+    x = np.zeros(32, np.float32)
+    x[0], x[1] = 8193.0, -3.0
+    _check_idempotence(x, 3)
 
 
 def test_property_roundtrip_error_bound_seeded():

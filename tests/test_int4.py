@@ -2,10 +2,12 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
+from repro.configs import get_arch, list_archs
 from repro.layers.common import QuantizedWeight, weight_dequant
 from repro.models.config import ModelConfig
-from repro.models.init import init_params
+from repro.models.init import init_packed_params, init_params
 from repro.quant.int4 import (fake_quant_params, fake_quant_weight,
                               pack_params, quantize_weight)
 
@@ -52,3 +54,19 @@ def test_quant_error_reasonable():
     # int4 symmetric g128 on gaussians: step = absmax/7 ~ 0.43 sigma,
     # E|err| ~ step/4 ~ 0.11 sigma vs E|w| = 0.8 sigma
     assert rel < 0.15
+
+
+@pytest.mark.parametrize("arch", sorted(list_archs()))
+def test_init_packed_params_equals_pack_of_init(arch):
+    """The layer-by-layer serving initializer draws the same weights from
+    the same seed as the whole-tree pair it replaces, bit for bit."""
+    cfg = get_arch(arch).smoke
+    want = pack_params(init_params(cfg, jax.random.PRNGKey(3)))
+    got = init_packed_params(cfg, jax.random.PRNGKey(3))
+    w_leaves, w_def = jax.tree_util.tree_flatten_with_path(want)
+    g_leaves, g_def = jax.tree_util.tree_flatten_with_path(got)
+    assert w_def == g_def
+    for (path, a), (_, b) in zip(w_leaves, g_leaves):
+        assert a.shape == b.shape and a.dtype == b.dtype, path
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                      err_msg=jax.tree_util.keystr(path))

@@ -260,7 +260,7 @@ def test_decode_fused_start_masking():
             bfp.unpack_int4(km4[b], axis=-1).reshape(S, Hkv * hd),
             ke4[b].reshape(S, Hkv * hd // 32), 4).reshape(S, Hkv, hd)
         vum = bfp.unpack_int4(vm4[b], axis=0)            # (S, Hkv, hd)
-        step = jnp.exp2(ve4[b].astype(jnp.float32) - 2.0)
+        step = bfp.pow2(ve4[b].astype(jnp.float32) - 2.0)
         v = (vum.astype(jnp.float32).reshape(S // 32, 32, Hkv, hd)
              * step[:, None]).reshape(S, Hkv, hd)
         pos = np.arange(S)

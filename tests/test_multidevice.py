@@ -119,6 +119,27 @@ def test_cache_and_params_actually_sharded():
         caches["_pos"].shape)) == caches["_pos"].size
 
 
+@pytest.mark.parametrize("name", ARCHS)
+def test_init_packed_params_born_sharded(name):
+    """Under a mesh the layer-by-layer initializer writes every packed
+    leaf straight into its ``param_pspecs`` sharding, with the same
+    values as the single-device tree."""
+    from repro.distributed.sharding import param_pspecs
+    from repro.models.init import init_packed_params
+    mesh = make_debug_mesh(2, 2)
+    cfg = _cfg(name)
+    got = init_packed_params(cfg, jax.random.PRNGKey(0), mesh=mesh)
+    want = _params(name)
+    specs = jax.tree.leaves(param_pspecs(cfg, want, mesh),
+                            is_leaf=lambda x: isinstance(
+                                x, jax.sharding.PartitionSpec))
+    for g, w, spec in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                          specs):
+        assert g.sharding.mesh.shape == mesh.shape
+        assert g.sharding.spec == spec
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 def _per_device_bytes(tree) -> int:
     return sum(l.addressable_shards[0].data.size * l.dtype.itemsize
                for l in jax.tree.leaves(tree))

@@ -80,6 +80,14 @@ def test_cache_pspecs_shard_batch_and_tail():
     assert "data" in str(k_spec) or ("data",) in tuple(k_spec)
 
 
+def _axes(entry) -> tuple:
+    """A PartitionSpec entry as a tuple of mesh-axis names: jax 0.9 stores
+    a one-axis tuple such as ``("data",)`` as the bare name ``"data"``."""
+    if entry is None:
+        return ()
+    return (entry,) if isinstance(entry, str) else tuple(entry)
+
+
 def test_cache_pspecs_overlay_slab_layout():
     """Field-aware specs on the PR 2 overlay/slab cache layout: batch at
     the scan-stacked axis 2, kv-heads (divisible) on model for every
@@ -96,7 +104,7 @@ def test_cache_pspecs_overlay_slab_layout():
     for name in ("k_init_mant", "k_bulk_mant", "v_bulk_mant",
                  "v_local_exp"):
         s = tuple(getattr(attn, name))
-        assert s[2] == ("data",), (name, s)      # batch under the stack
+        assert _axes(s[2]) == ("data",), (name, s)   # batch under the stack
         assert "model" in s, (name, s)           # kv-heads sharded
         assert s[3] is None, (name, s)           # token axis never split
     assert tuple(attn.length) == (None, None)    # shared counter
