@@ -56,7 +56,7 @@ BENCH_JSON = os.path.join(REPO_ROOT, "BENCH_kernels.json")
 
 def timeit(fn, *args, n=5):
     """(min-of-n microseconds, output) — min is robust to CPU contention
-    spikes, mirroring decode_throughput's best-of policy."""
+    spikes."""
     out = fn(*args)  # compile
     jax.block_until_ready(out)
     best = float("inf")

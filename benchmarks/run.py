@@ -7,11 +7,6 @@ without scraping per-module JSONs.  ``--fast`` trims sweeps (CI); default
 runs the full grids.
 
   PYTHONPATH=src python -m benchmarks.run [--fast] [--only fig4,...]
-
-``serve_scaling`` needs forced-host devices before the first jax import;
-under the orchestrator (where an earlier module usually imported jax
-already) it is skipped with that recipe unless 8 devices are visible —
-run it standalone or via the CI multidevice job.
 """
 from __future__ import annotations
 
@@ -36,20 +31,7 @@ MODULES = [
     ("fig1618", "benchmarks.fig1618_accelerators"),
     ("fig19", "benchmarks.fig19_seqlen"),
     ("kernels", "benchmarks.kernels_micro"),
-    ("decode", "benchmarks.decode_throughput"),
-    ("serve", "benchmarks.serve_scaling"),
 ]
-
-
-def _skip_reason(key: str) -> str | None:
-    if key == "serve":
-        import jax
-        if jax.device_count() < 8:
-            return ("needs 8 forced-host devices: run `PYTHONPATH=src "
-                    "python -m benchmarks.serve_scaling` standalone (it "
-                    "sets XLA_FLAGS before importing jax) or the CI "
-                    "multidevice job")
-    return None
 
 
 def main() -> None:
@@ -72,12 +54,6 @@ def main() -> None:
         if only and key not in only:
             continue
         t0 = time.time()
-        reason = _skip_reason(key)
-        if reason is not None:
-            summary["modules"][key] = {"status": "skipped",
-                                       "reason": reason}
-            print(f"{key}.TOTAL,0,SKIPPED:{reason}")
-            continue
         try:
             mod = __import__(modname, fromlist=["main"])
             result = mod.main(fast=args.fast)

@@ -150,9 +150,14 @@ def run_serve_loop(eng: Engine, prompts):
           and all(isinstance(t, str) for t in texts),
           "ServeLoop left requests unanswered")
     check(loop.stats["chunks"] >= 1, f"ServeLoop stats {loop.stats}")
+    check(all(len(r.tokens) >= 1 for r in loop.records),
+          "ServeLoop served a request no token")
+    first = [r.first_token - t0 for r in loop.records]
+    done = [r.finished - t0 for r in loop.records]
     log(f"ServeLoop.serve: {len(texts)} requests at batch {SERVE_BATCH}, "
         f"{loop.stats}, {time.perf_counter() - t0:.1f} s (compile "
-        f"included)")
+        f"included); first tokens at {min(first):.1f}-{max(first):.1f} s, "
+        f"requests done at {min(done):.1f}-{max(done):.1f} s")
     return texts
 
 

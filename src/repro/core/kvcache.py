@@ -347,7 +347,7 @@ def append_token(cache: AsymKVCache, k_new: jax.Array,
     V group g-2.
 
     ``legacy=True`` dispatches to the pre-fused-loop select-based
-    formulation (the decode-throughput benchmark baseline): bit-identical
+    formulation (the reference of ``tests/test_kvcache.py``): bit-identical
     values, whole-buffer ``jnp.where`` data movement.
     """
     if legacy:
@@ -434,7 +434,7 @@ def gather_kv(cache: AsymKVCache, dtype=jnp.float32, *,
     """Dequantize the full cache into position order.
 
     ``legacy=True`` dispatches to the scatter/`.at[].set` formulation (the
-    decode-throughput benchmark baseline) — bit-identical values.
+    reference of ``tests/test_kvcache.py``) — bit-identical values.
 
     Returns (k, v, valid) where k/v: (B, max_seq, n_kv, hd) and
     valid: (max_seq,) bool (position < length).  The k_offsets are *not*
@@ -523,8 +523,8 @@ def gather_kv(cache: AsymKVCache, dtype=jnp.float32, *,
 
 
 # ---------------------------------------------------------------------------
-# Legacy (pre-fused-loop) formulations, kept as the decode-throughput
-# benchmark baseline (same values bit-for-bit, different data movement),
+# Legacy (pre-fused-loop) formulations, kept as the reference of
+# tests/test_kvcache.py (same values bit-for-bit, different data movement),
 # reached through ``append_token(..., legacy=True)`` /
 # ``gather_kv(..., legacy=True)``:
 #   * _append_token_select — whole-buffer jnp.where selects around every

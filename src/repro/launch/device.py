@@ -21,7 +21,13 @@ def enable_compile_cache() -> str:
     ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
     wins: no other directory is set in code.  Otherwise the cache is the
     fixed ``.jax_cache/`` at the root of the checkout — a fixed path,
-    since the path is part of what a cache entry is found by."""
+    since the path is part of what a cache entry is found by.
+
+    Entries are keyed on the programs' metadata too.  The named scopes a
+    profiler trace is reduced by are metadata only, so by default a
+    program would load an entry compiled from the same instructions with
+    other scopes, or none, and its trace would show those."""
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if path:
         return path

@@ -30,6 +30,7 @@ from __future__ import annotations
 import argparse
 import os
 import re
+import time
 
 
 def _parse_mesh(s: str):
@@ -125,12 +126,14 @@ def main():
     if args.continuous:
         loop = ServeLoop(eng, batch_size=args.batch_size,
                          max_steps=args.max_steps)
+        t0 = time.perf_counter()
         texts = loop.serve(args.prompts)
-        for p, t in zip(args.prompts, texts):
-            print(f"[serve] {p!r} -> {t!r}")
-        print(f"[serve] continuous batching: {loop.stats['waves']} waves, "
-              f"{loop.stats['chunks']} chunks, {loop.stats['swaps']} "
-              f"row swaps")
+        for p, t, rec in zip(args.prompts, texts, loop.records):
+            print(f"[serve] {p!r} -> {t!r} ({len(rec.tokens)} tokens, "
+                  f"first token {rec.first_token - t0:.3f} s, "
+                  f"done {rec.finished - t0:.3f} s)")
+        print("[serve] continuous batching: " + ", ".join(
+            f"{k} {v}" for k, v in loop.stats.items()))
         return
 
     out = eng.generate(args.prompts)

@@ -464,7 +464,6 @@ def attention_decode_packed(q: jax.Array, cache: kvcache.AsymKVCache, *,
                             seq_shard: bool = False,
                             dp_axes: tuple = ("data",),
                             use_pallas: bool = False,
-                            legacy: bool = False,
                             single_launch: bool = True,
                             interpret: Optional[bool] = None) -> jax.Array:
     """One-token decode: q (B,1,H,hd) against the packed asymmetric cache.
@@ -491,39 +490,39 @@ def attention_decode_packed(q: jax.Array, cache: kvcache.AsymKVCache, *,
     if use_pallas and not seq_shard:
         fn = (_decode_packed_pallas_single if single_launch
               else _decode_packed_pallas)
-        return fn(
-            q, cache, logit_cap=logit_cap, quant=quant,
-            extra_invalid_prefix=extra_invalid_prefix, interpret=interpret)
-    q = _quant_qk(q, quant)
-    if legacy:
-        # pre-fused-loop formulation (decode-throughput baseline): the
-        # scatter-based gather straight into bf16
-        k, v, valid = kvcache.gather_kv(cache, dtype=jnp.bfloat16,
-                                        legacy=True)
-    else:
+        with jax.named_scope("attend"):
+            return fn(
+                q, cache, logit_cap=logit_cap, quant=quant,
+                extra_invalid_prefix=extra_invalid_prefix,
+                interpret=interpret)
+    with jax.named_scope("attend"):
+        q = _quant_qk(q, quant)
+    with jax.named_scope("kv_gather"):
         # gather in f32 and cast once: identical values (the dequants
         # compute in f32 either way; cast commutes with the pure data
         # movement), but ~1.6x faster on XLA CPU, where bf16 elementwise
         # lowers poorly
         k, v, valid = kvcache.gather_kv(cache, dtype=jnp.float32)
         k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
-    if seq_shard:
-        # keep head_dim sharded through the QK contraction: partial score
-        # rows all-reduce (~40 MiB) instead of all-gathering the entire
-        # dequantized K cache (~1 GiB/layer measured; §Perf iteration 3)
-        from jax.sharding import PartitionSpec as P
-        wsc = jax.lax.with_sharding_constraint
-        k = wsc(k, P(dp_axes, None, None, "model"))
-        v = wsc(v, P(dp_axes, None, None, "model"))
-        q = wsc(q, P(dp_axes, None, None, "model"))
-    scores = _group_heads(q, k) / jnp.sqrt(float(hd))   # (B,Hkv,rep,1,T)
-    m = valid[None, :]
-    if extra_invalid_prefix is not None:
-        pos = jnp.arange(k.shape[1])[None, :]
-        m = m & (pos >= extra_invalid_prefix[:, None])
-    p = _masked_softmax(scores, m[:, None, None, None], logit_cap)
-    p = _quant_p(p, quant)
-    return _apply_scores_v(p, v)
+    with jax.named_scope("attend"):
+        if seq_shard:
+            # keep head_dim sharded through the QK contraction: partial
+            # score rows all-reduce (~40 MiB) instead of all-gathering the
+            # entire dequantized K cache (~1 GiB/layer measured; §Perf
+            # iteration 3)
+            from jax.sharding import PartitionSpec as P
+            wsc = jax.lax.with_sharding_constraint
+            k = wsc(k, P(dp_axes, None, None, "model"))
+            v = wsc(v, P(dp_axes, None, None, "model"))
+            q = wsc(q, P(dp_axes, None, None, "model"))
+        scores = _group_heads(q, k) / jnp.sqrt(float(hd))  # (B,Hkv,rep,1,T)
+        m = valid[None, :]
+        if extra_invalid_prefix is not None:
+            pos = jnp.arange(k.shape[1])[None, :]
+            m = m & (pos >= extra_invalid_prefix[:, None])
+        p = _masked_softmax(scores, m[:, None, None, None], logit_cap)
+        p = _quant_p(p, quant)
+        return _apply_scores_v(p, v)
 
 
 # ---------------------------------------------------------------------------

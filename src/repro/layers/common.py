@@ -23,24 +23,26 @@ from repro.core.quant_config import QuantConfig
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6,
              zero_centered: bool = False) -> jax.Array:
     """RMSNorm in fp32 (gemma uses (1 + scale) — ``zero_centered``)."""
-    dt = x.dtype
-    xf = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-    xf = xf * jax.lax.rsqrt(var + eps)
-    w = (1.0 + scale.astype(jnp.float32)) if zero_centered \
-        else scale.astype(jnp.float32)
-    return (xf * w).astype(dt)
+    with jax.named_scope("norm"):
+        dt = x.dtype
+        xf = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+        xf = xf * jax.lax.rsqrt(var + eps)
+        w = (1.0 + scale.astype(jnp.float32)) if zero_centered \
+            else scale.astype(jnp.float32)
+        return (xf * w).astype(dt)
 
 
 def layer_norm(x: jax.Array, scale: jax.Array, bias: jax.Array,
                eps: float = 1e-5) -> jax.Array:
-    dt = x.dtype
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.var(xf, axis=-1, keepdims=True)
-    xf = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (xf * scale.astype(jnp.float32)
-            + bias.astype(jnp.float32)).astype(dt)
+    with jax.named_scope("norm"):
+        dt = x.dtype
+        xf = x.astype(jnp.float32)
+        mu = jnp.mean(xf, axis=-1, keepdims=True)
+        var = jnp.var(xf, axis=-1, keepdims=True)
+        xf = (xf - mu) * jax.lax.rsqrt(var + eps)
+        return (xf * scale.astype(jnp.float32)
+                + bias.astype(jnp.float32)).astype(dt)
 
 
 def activation(x: jax.Array, kind: str) -> jax.Array:
@@ -117,17 +119,25 @@ def qlinear(x: jax.Array, w: WeightLike, quant: Optional[QuantConfig] = None,
       ``QuantizedWeight`` (serving; dequantized on the fly — on TPU the
       Pallas ``bfp_matmul`` kernel fuses this; the XLA path here is the
       portable fallback with identical numerics).
+
+    Its work runs under the named scope ``qlinear``, split into
+    ``act_quant``, ``weight_dequant`` and ``matmul`` (bias included).
     """
-    if quant is not None and quant.enabled and quant.quant_linear_acts \
-            and quantize_input:
-        x = bfp.bfp_fake_quant(x, quant.group_size, quant.act_mantissa_bits,
-                               quant.rounding, axis=-1, ste=quant.ste)
-    if isinstance(w, QuantizedWeight):
-        w = weight_dequant(w, x.dtype)
-    y = jnp.einsum("...i,io->...o", x, w)
-    if bias is not None:
-        y = y + bias.astype(y.dtype)
-    return y
+    with jax.named_scope("qlinear"):
+        if quant is not None and quant.enabled and quant.quant_linear_acts \
+                and quantize_input:
+            with jax.named_scope("act_quant"):
+                x = bfp.bfp_fake_quant(x, quant.group_size,
+                                       quant.act_mantissa_bits,
+                                       quant.rounding, axis=-1, ste=quant.ste)
+        if isinstance(w, QuantizedWeight):
+            with jax.named_scope("weight_dequant"):
+                w = weight_dequant(w, x.dtype)
+        with jax.named_scope("matmul"):
+            y = jnp.einsum("...i,io->...o", x, w)
+            if bias is not None:
+                y = y + bias.astype(y.dtype)
+        return y
 
 
 def embed_lookup(tokens: jax.Array, table: jax.Array,

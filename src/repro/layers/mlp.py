@@ -12,18 +12,22 @@ from repro.layers.common import activation, qlinear
 
 def gated_mlp(x: jax.Array, p: dict, act: str,
               quant: Optional[QuantConfig] = None) -> jax.Array:
-    """SwiGLU-style MLP: down( act(gate(x)) * up(x) )."""
+    """SwiGLU-style MLP: down( act(gate(x)) * up(x) ); the activation and
+    gate run under the named scope ``mlp_act``."""
     g = qlinear(x, p["w_gate"], quant)
     u = qlinear(x, p["w_up"], quant)
-    h = activation(g, act) * u
+    with jax.named_scope("mlp_act"):
+        h = activation(g, act) * u
     return qlinear(h, p["w_down"], quant)
 
 
 def plain_mlp(x: jax.Array, p: dict, act: str,
               quant: Optional[QuantConfig] = None) -> jax.Array:
-    """2-layer MLP (Whisper / classic transformer)."""
-    h = activation(qlinear(x, p["w_up"], quant,
-                           bias=p.get("b_up")), act)
+    """2-layer MLP (Whisper / classic transformer); the activation runs
+    under the named scope ``mlp_act``."""
+    h = qlinear(x, p["w_up"], quant, bias=p.get("b_up"))
+    with jax.named_scope("mlp_act"):
+        h = activation(h, act)
     return qlinear(h, p["w_down"], quant, bias=p.get("b_down"))
 
 

@@ -47,9 +47,6 @@ class Ctx:
     dp_axes: tuple = ("data",)     # + production meshes only)
     use_pallas: bool = False       # grid-fused Pallas kernels on the
                                    # prefill/decode global-attn hot paths
-    legacy_cache: bool = False     # pre-fused-loop cache ops (select-based
-                                   # append + scatter gather) — the decode
-                                   # throughput benchmark baseline
 
 
 def _c(x, ctx: Ctx, *spec):
@@ -76,7 +73,8 @@ def _mlp_part(h, p, cfg: ModelConfig, quant):
         y = plain_mlp(x, p, cfg.act_fn, quant)
     if cfg.post_block_norm:
         y = _norm(y, p, "post_ln2", cfg)
-    return h + y
+    with jax.named_scope("residual"):
+        return h + y
 
 
 def _qkv(x, p, cfg: ModelConfig, quant, prefix=""):
@@ -118,11 +116,11 @@ def _cross_attention(h, p, cfg: ModelConfig, quant, ctx: Ctx,
     return h + out, (k, v)
 
 
-def _attn_block(h, p, kind: str, cfg: ModelConfig,
-                quant: Optional[QuantConfig], ctx: Ctx, cache):
-    B, S, _ = h.shape
-    x = _norm(h, p, "ln1", cfg)
-    q, k, v = _qkv(x, p, cfg, quant)
+def _attention(q, k, v, kind: str, cfg: ModelConfig,
+               quant: Optional[QuantConfig], ctx: Ctx, cache):
+    """Rotary embedding, the attention itself and the cache build or
+    append of one attention block: (attn, new cache)."""
+    B, S = q.shape[:2]
     if cfg.pos_embed == "rope":
         q = apply_rope(q, ctx.positions, cfg.rope_theta)
         k = apply_rope(k, ctx.positions, cfg.rope_theta)
@@ -141,81 +139,99 @@ def _attn_block(h, p, kind: str, cfg: ModelConfig,
     new_cache = cache
 
     if ctx.mode == "full":
-        if online:
-            w = min(quant.smoothing.online_window, S)
-            off = compute_online_offsets(k[:, :w].astype(jnp.float32),
-                                         quant.smoothing.online_topk)
-            k = k - off[:, None].astype(k.dtype)
-        if ctx.eval_kv and quant is not None and quant.enabled \
-                and quant.quant_attention:
-            attn = attn_lib.attention_eval_quant(
-                q, k, v, ctx.positions, quant, mask_kind=mask_kind,
-                window=window, logit_cap=cfg.attn_logit_softcap,
-                k_valid=ctx.k_valid)
-        else:
-            attn = attn_lib.attention_forward(
-                q, k, v, ctx.positions, mask_kind=mask_kind, window=window,
-                logit_cap=cfg.attn_logit_softcap, quant=quant,
-                k_valid=ctx.k_valid)
+        with jax.named_scope("attend"):
+            if online:
+                w = min(quant.smoothing.online_window, S)
+                off = compute_online_offsets(k[:, :w].astype(jnp.float32),
+                                             quant.smoothing.online_topk)
+                k = k - off[:, None].astype(k.dtype)
+            if ctx.eval_kv and quant is not None and quant.enabled \
+                    and quant.quant_attention:
+                attn = attn_lib.attention_eval_quant(
+                    q, k, v, ctx.positions, quant, mask_kind=mask_kind,
+                    window=window, logit_cap=cfg.attn_logit_softcap,
+                    k_valid=ctx.k_valid)
+            else:
+                attn = attn_lib.attention_forward(
+                    q, k, v, ctx.positions, mask_kind=mask_kind,
+                    window=window, logit_cap=cfg.attn_logit_softcap,
+                    quant=quant, k_valid=ctx.k_valid)
     elif ctx.mode == "prefill":
         # grid-fused Pallas path: engine-style causal prefill (arange
         # positions, no padding mask, un-sharded) on the global-attn kind
         pallas_ok = (ctx.use_pallas and kind == "attn" and not ctx.bidir
                      and ctx.k_valid is None and not ctx.seq_shard
                      and S % 32 == 0 and cfg.head_dim % 32 == 0)
-        if pallas_ok:
-            attn = attn_lib.attention_prefill_pallas(
-                q, k, v, causal=True, logit_cap=cfg.attn_logit_softcap,
-                quant=quant)
-        else:
-            attn = attn_lib.attention_forward(
-                q, k, v, ctx.positions, mask_kind=mask_kind, window=window,
-                logit_cap=cfg.attn_logit_softcap, quant=quant,
-                k_valid=ctx.k_valid)
-        if kind == "attn":
-            off = None
-            if online:
-                w = min(quant.smoothing.online_window, S)
-                off = compute_online_offsets(
-                    k[:, :w].astype(jnp.float32),
-                    quant.smoothing.online_topk)
-            c = kvcache.init_cache(B, cfg.n_kv_heads, cfg.head_dim,
-                                   ctx.max_seq)
-            # same guard as the attention kernel: the packed cache is
-            # built by the single-launch FP->BFP converter kernel (only
-            # packed bytes hit HBM) instead of the XLA quantize chains
-            new_cache = kvcache.prefill_cache(
-                c, k.astype(jnp.float32), v.astype(jnp.float32), off,
-                use_pallas=pallas_ok)
-        else:
-            c = attn_lib.init_ring_cache(B, cfg.n_kv_heads, cfg.head_dim,
-                                         min(cfg.window_size, ctx.max_seq))
-            new_cache = attn_lib.ring_prefill(
-                c, k.astype(jnp.float32), v.astype(jnp.float32))
+        with jax.named_scope("attend"):
+            if pallas_ok:
+                attn = attn_lib.attention_prefill_pallas(
+                    q, k, v, causal=True, logit_cap=cfg.attn_logit_softcap,
+                    quant=quant)
+            else:
+                attn = attn_lib.attention_forward(
+                    q, k, v, ctx.positions, mask_kind=mask_kind,
+                    window=window, logit_cap=cfg.attn_logit_softcap,
+                    quant=quant, k_valid=ctx.k_valid)
+        with jax.named_scope("kv_convert"):
+            if kind == "attn":
+                off = None
+                if online:
+                    w = min(quant.smoothing.online_window, S)
+                    off = compute_online_offsets(
+                        k[:, :w].astype(jnp.float32),
+                        quant.smoothing.online_topk)
+                c = kvcache.init_cache(B, cfg.n_kv_heads, cfg.head_dim,
+                                       ctx.max_seq)
+                # same guard as the attention kernel: the packed cache is
+                # built by the single-launch FP->BFP converter kernel
+                # (only packed bytes hit HBM) instead of the XLA quantize
+                # chains
+                new_cache = kvcache.prefill_cache(
+                    c, k.astype(jnp.float32), v.astype(jnp.float32), off,
+                    use_pallas=pallas_ok)
+            else:
+                c = attn_lib.init_ring_cache(
+                    B, cfg.n_kv_heads, cfg.head_dim,
+                    min(cfg.window_size, ctx.max_seq))
+                new_cache = attn_lib.ring_prefill(
+                    c, k.astype(jnp.float32), v.astype(jnp.float32))
     elif ctx.mode == "decode":
         if kind == "attn":
-            new_cache = kvcache.append_token(cache, k[:, 0], v[:, 0],
-                                             legacy=ctx.legacy_cache)
+            with jax.named_scope("kv_append"):
+                new_cache = kvcache.append_token(cache, k[:, 0], v[:, 0])
+            # splits its work into kv_gather and attend
             attn = attn_lib.attention_decode_packed(
                 q, new_cache, logit_cap=cfg.attn_logit_softcap, quant=quant,
                 extra_invalid_prefix=ctx.pad_prefix,
                 seq_shard=ctx.seq_shard, dp_axes=ctx.dp_axes,
-                use_pallas=ctx.use_pallas, legacy=ctx.legacy_cache)
+                use_pallas=ctx.use_pallas)
         else:
-            new_cache = attn_lib.ring_append(cache, k[:, 0], v[:, 0])
-            attn = attn_lib.ring_decode_attention(
-                q, new_cache, window=cfg.window_size,
-                logit_cap=cfg.attn_logit_softcap, quant=quant)
+            with jax.named_scope("kv_append"):
+                new_cache = attn_lib.ring_append(cache, k[:, 0], v[:, 0])
+            with jax.named_scope("attend"):
+                attn = attn_lib.ring_decode_attention(
+                    q, new_cache, window=cfg.window_size,
+                    logit_cap=cfg.attn_logit_softcap, quant=quant)
     else:
         raise ValueError(ctx.mode)
+    return attn, new_cache
 
-    attn = attn.astype(h.dtype).reshape(B, S, cfg.q_dim)
+
+def _attn_block(h, p, kind: str, cfg: ModelConfig,
+                quant: Optional[QuantConfig], ctx: Ctx, cache):
+    B, S, _ = h.shape
+    x = _norm(h, p, "ln1", cfg)
+    q, k, v = _qkv(x, p, cfg, quant)
+    with jax.named_scope("attention"):
+        attn, new_cache = _attention(q, k, v, kind, cfg, quant, ctx, cache)
+        attn = attn.astype(h.dtype).reshape(B, S, cfg.q_dim)
     if ctx.seq_shard and ctx.mode in ("full", "prefill"):
         attn = _c(attn, ctx, ctx.dp_axes, "model", None)
     out = qlinear(attn, p["wo"], quant)
     if cfg.post_block_norm:
         out = _norm(out, p, "post_ln1", cfg)
-    h = h + out
+    with jax.named_scope("residual"):
+        h = h + out
     if ctx.seq_shard and ctx.mode in ("full", "prefill"):
         # Megatron-SP residual: S-sharded between blocks -> row-sharded
         # projections reduce-scatter instead of all-reduce; norms shard
@@ -322,13 +338,16 @@ def _run_stack(h, blocks: Dict, cfg: ModelConfig, quant, ctx: Ctx,
     xs = {k: (scan_params[k],
               caches["scan"].get(k) if caches is not None else None)
           for k in c}
-    h, ys = jax.lax.scan(step_fn, h, xs, unroll=n_rep if unroll else 1)
-
-    rem_caches = []
-    for j, (kind, p_j) in enumerate(rem_params):
-        c_j = caches["rem"][j] if caches is not None else None
-        h, c_new = apply_block(h, p_j, kind, cfg, quant, ctx, c_j)
-        rem_caches.append(c_new)
+    # the scan's own work (each layer's weights and cache sliced out of
+    # the stacks, the new cache written back) runs under "layers"
+    with jax.named_scope("layers"):
+        h, ys = jax.lax.scan(step_fn, h, xs,
+                             unroll=n_rep if unroll else 1)
+        rem_caches = []
+        for j, (kind, p_j) in enumerate(rem_params):
+            c_j = caches["rem"][j] if caches is not None else None
+            h, c_new = apply_block(h, p_j, kind, cfg, quant, ctx, c_j)
+            rem_caches.append(c_new)
 
     new_caches = None
     if ctx.mode in ("prefill", "decode"):
@@ -359,10 +378,12 @@ def encoder_forward(params, cfg: ModelConfig, frames: jax.Array,
 def _embed(params, cfg: ModelConfig, tokens, positions):
     import math
     scale = math.sqrt(cfg.d_model) if cfg.embed_scale else 1.0
-    h = embed_lookup(tokens, params["embed"], scale)
-    if cfg.pos_embed == "sinusoidal":
-        h = h + sinusoidal_embedding(positions, cfg.d_model).astype(h.dtype)
-    return h
+    with jax.named_scope("embed"):
+        h = embed_lookup(tokens, params["embed"], scale)
+        if cfg.pos_embed == "sinusoidal":
+            h = h + sinusoidal_embedding(positions,
+                                         cfg.d_model).astype(h.dtype)
+        return h
 
 
 def head_logits(params, cfg: ModelConfig, h, quant=None):
@@ -377,8 +398,9 @@ def head_logits(params, cfg: ModelConfig, h, quant=None):
 
 
 def _head(params, cfg: ModelConfig, h, quant=None):
-    h = _norm(h, params, "final_norm", cfg)
-    return head_logits(params, cfg, h, quant)
+    with jax.named_scope("head"):
+        h = _norm(h, params, "final_norm", cfg)
+        return head_logits(params, cfg, h, quant)
 
 
 def _prepend_frontend(h, positions, frontend_embeds):
@@ -468,16 +490,14 @@ def decode_step(params, cfg: ModelConfig, token: jax.Array, caches, *,
                 quant: Optional[QuantConfig] = None,
                 pad_prefix: Optional[jax.Array] = None,
                 unroll: bool = False, seq_shard: bool = False,
-                dp_axes: tuple = ("data",), use_pallas: bool = False,
-                legacy_cache: bool = False):
+                dp_axes: tuple = ("data",), use_pallas: bool = False):
     """token: (B,) -> (logits (B, V), new caches)."""
     B = token.shape[0]
     t = caches["_pos"]
     positions = jnp.broadcast_to(t[None, None], (B, 1)).astype(jnp.int32)
     h = _embed(params, cfg, token[:, None], positions)
     ctx = Ctx(mode="decode", positions=positions, pad_prefix=pad_prefix,
-              seq_shard=seq_shard, dp_axes=dp_axes, use_pallas=use_pallas,
-              legacy_cache=legacy_cache)
+              seq_shard=seq_shard, dp_axes=dp_axes, use_pallas=use_pallas)
     h, new_caches = _run_stack(h, params["blocks"], cfg, quant, ctx, caches,
                                unroll=unroll)
     new_caches["_pos"] = t + 1
@@ -543,14 +563,20 @@ def generate_loop(params, cfg: ModelConfig, caches, *, num_steps: int,
     if key is None:
         key = jax.random.PRNGKey(0)
 
+    def sample(lg, k, fin):
+        """The next token of every row, frozen to EOS where finished."""
+        with jax.named_scope("sample"):
+            nxt = sample_fn(lg, k).astype(jnp.int32)
+            if eos_id is not None:
+                nxt = jnp.where(fin, jnp.int32(eos_id), nxt)
+                fin = fin | (nxt == eos_id)
+            return nxt, fin
+
     if logits0 is not None:
         B = logits0.shape[0]
         if finished is None:
             finished = jnp.zeros((B,), bool)
-        tok = sample_fn(logits0, key).astype(jnp.int32)
-        if eos_id is not None:
-            tok = jnp.where(finished, jnp.int32(eos_id), tok)
-            finished = finished | (tok == eos_id)
+        tok, finished = sample(logits0, key, finished)
         emit_first = tok[:, None]
         n_scan = num_steps - 1
     else:
@@ -571,10 +597,7 @@ def generate_loop(params, cfg: ModelConfig, caches, *, num_steps: int,
         if cache_shardings is not None:
             cs = jax.tree.map(jax.lax.with_sharding_constraint, cs,
                               cache_shardings)
-        nxt = sample_fn(lg, sk).astype(jnp.int32)
-        if eos_id is not None:
-            nxt = jnp.where(fin, jnp.int32(eos_id), nxt)
-            fin = fin | (nxt == eos_id)
+        nxt, fin = sample(lg, sk, fin)
         return (nxt, cs, k, fin), nxt
 
     (tok, caches, key, finished), toks = jax.lax.scan(

@@ -44,6 +44,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import kvcache
 from repro.core.quant_config import QuantConfig, harmonia
@@ -141,13 +142,17 @@ class Engine:
             lambda p, t: lm.prefill(p, cfg, t, max_seq=ecfg.max_seq,
                                     quant=self.quant,
                                     use_pallas=ecfg.use_pallas_kernels))
+
+        def serve_step(p, t, c, pp):
+            """One decode step of the host loop."""
+            return lm.decode_step(p, cfg, t, c, quant=self.quant,
+                                  pad_prefix=pp,
+                                  use_pallas=ecfg.use_pallas_kernels)
+
         # donate the cache: append_token's predicated writes let XLA alias
         # every region buffer in place instead of allocating a second cache
-        self._decode = jax.jit(
-            lambda p, t, c, pp: lm.decode_step(
-                p, cfg, t, c, quant=self.quant, pad_prefix=pp,
-                use_pallas=ecfg.use_pallas_kernels),
-            donate_argnums=2)
+        self._serve_step = serve_step
+        self._decode = jax.jit(serve_step, donate_argnums=2)
         self._sample: Callable = sampler_lib.make_sampler(
             ecfg.sampler, temperature_value=ecfg.temperature)
         if self.mesh is not None:
@@ -232,9 +237,7 @@ class Engine:
         if key not in self._mesh_jits:
             c_sh = self.cache_shardings(B)
             self._mesh_jits[key] = jax.jit(
-                lambda p, t, c, pp: lm.decode_step(
-                    p, self.cfg, t, c, quant=self.quant, pad_prefix=pp,
-                    use_pallas=self.ecfg.use_pallas_kernels),
+                self._serve_step,
                 in_shardings=(self._param_sh, None, c_sh, None),
                 out_shardings=(self._batch_sh(B), c_sh),
                 donate_argnums=2)
@@ -251,8 +254,11 @@ class Engine:
         key = ("scatter", batch, len(rows))
         if key not in self._mesh_jits:
             c_sh = self.cache_shardings(batch)
+
+            def serve_swap_rows(d, s, r):
+                return scatter_rows(d, s, r, batch)
             self._mesh_jits[key] = jax.jit(
-                lambda d, s, r: scatter_rows(d, s, r, batch),
+                serve_swap_rows,
                 in_shardings=(c_sh, self.cache_shardings(len(rows)), None),
                 out_shardings=c_sh, donate_argnums=0)
         return self._mesh_jits[key](dst, src, jnp.asarray(list(rows)))
@@ -303,26 +309,28 @@ class Engine:
         return self._loops[memo_key]
 
     # -- batching --
-    def _prepare(self, prompts: List[str], pad_to: Optional[int] = None):
+    def _encode(self, prompt: str) -> List[int]:
+        """Token ids of ``prompt``, truncated to leave one ALIGN block of
+        the cache for generation."""
+        return self.tok.encode(prompt)[: self.ecfg.max_seq - ALIGN]
+
+    def _prepare(self, prompts: List[str]):
         """Encode, truncate, vocab-clip and left-pad to a shared
-        ALIGN-multiple length (``pad_to`` overrides it — the serving
-        loop's row re-prefill at the shared position counter)."""
+        ALIGN-multiple length."""
         if not prompts:
             raise ValueError("prompts must be a non-empty list")
-        ids = [self.tok.encode(p)[: self.ecfg.max_seq - ALIGN]
-               for p in prompts]
-        longest = max((len(x) for x in ids), default=0)
-        # all-empty prompt lists would otherwise yield padded_len == 0 and
-        # degenerate (B, 0) model shapes — always allocate one ALIGN block
-        padded_len = max(ALIGN, ceil_align(longest))
-        if pad_to is not None:
-            if longest > pad_to or pad_to % ALIGN:
-                raise ValueError(f"cannot pad prompts of length {longest} "
-                                 f"to {pad_to}")
-            padded_len = pad_to
-        return self._pad_batch(ids, padded_len)
+        return self._pad_batch([self._encode(p) for p in prompts])
 
-    def _pad_batch(self, ids: List[List[int]], padded_len: int):
+    def _pad_batch(self, ids: List[List[int]],
+                   padded_len: Optional[int] = None):
+        """Left-pad ``ids`` to ``padded_len`` (the serving loop's row
+        re-prefill at the shared position counter), by default to the
+        longest one's ALIGN multiple: (tokens (B, S), pad_prefix (B,))."""
+        if padded_len is None:
+            # all-empty prompt lists would otherwise yield padded_len == 0
+            # and degenerate (B, 0) model shapes — always allocate one
+            # ALIGN block
+            padded_len = max(ALIGN, ceil_align(max(len(x) for x in ids)))
         B = len(ids)
         toks = np.full((B, padded_len), self.tok.pad_id, np.int32)
         pad_prefix = np.zeros((B,), np.int32)
@@ -408,6 +416,20 @@ class Engine:
                     self.quant.kv.storage_fraction(seq_len)}
 
 
+@dataclasses.dataclass
+class RequestRecord:
+    """One request's way through ``ServeLoop.serve``.  Times are
+    ``time.perf_counter()`` seconds."""
+    admitted: float                     # taken into a batch row
+    first_token: Optional[float] = None  # its first token on the host
+    finished: Optional[float] = None    # finalized
+    tokens: Optional[List[int]] = None  # served ids, cut at budget and EOS
+
+
+SERVE_STATS = ("waves", "chunks", "swaps", "prefills", "prefill_tokens",
+               "prefill_padded_tokens", "decode_steps", "decode_row_steps")
+
+
 class ServeLoop:
     """Continuous batching over the fused loop's chunked continuation.
 
@@ -421,6 +443,23 @@ class ServeLoop:
     current counter value.  When every row has drained and requests
     remain, a fresh wave restarts the counter instead (cheaper than
     re-prefilling at a long padded length).
+
+    What a ``serve`` call did, reset by the next one:
+
+    * ``stats``: ``waves``; ``chunks``; ``swaps`` (rows re-prefilled);
+      ``prefills`` (dispatches, swap-ins included); ``prefill_tokens``
+      (real prompt tokens) and ``prefill_padded_tokens`` (B x S
+      dispatched); ``decode_steps`` and ``decode_row_steps`` (steps x
+      batch rows);
+    * ``records``: a ``RequestRecord`` per request, in request order;
+    * host spans in the profiler's trace (``jax.profiler.
+      TraceAnnotation``, inert unless a trace is being taken):
+      ``serve.wave`` around each wave, and in it ``serve.prepare``
+      (encode and pad), ``serve.prefill`` (dispatch; carries its real
+      ``tokens`` and the ``padded`` B x S) and ``serve.chunk`` (dispatch;
+      carries its counter ``pos``, ``steps`` and ``rows``),
+      ``serve.swap_in``, ``serve.wait`` (the host blocked on a device
+      result) and ``serve.finalize``.
     """
 
     def __init__(self, engine: Engine, batch_size: int = 4,
@@ -428,7 +467,8 @@ class ServeLoop:
         self.engine = engine
         self.batch = batch_size
         self.max_steps = max(ALIGN, ceil_align(max_steps))
-        self.stats = {"waves": 0, "chunks": 0, "swaps": 0}
+        self.stats = dict.fromkeys(SERVE_STATS, 0)
+        self.records: List[Optional[RequestRecord]] = []
 
     def serve(self, prompts: List[str],
               max_new_tokens: Union[int, Sequence[int], None] = None
@@ -444,9 +484,11 @@ class ServeLoop:
                        or self.engine.ecfg.max_new_tokens] * len(prompts)
         results: List[Optional[str]] = [None] * len(prompts)
         queue = list(range(len(prompts)))
-        self.stats = {"waves": 0, "chunks": 0, "swaps": 0}
+        self.stats = dict.fromkeys(SERVE_STATS, 0)
+        self.records = [None] * len(prompts)
         while queue:
-            queue = self._run_wave(prompts, budgets, queue, results)
+            with TraceAnnotation("serve.wave"):
+                queue = self._run_wave(prompts, budgets, queue, results)
         return results
 
     # -- one wave: a batch of rows decoded to completion, with row swaps --
@@ -457,43 +499,76 @@ class ServeLoop:
         if eos in seq:
             seq = seq[: seq.index(eos)]
         results[req] = self.engine.tok.decode(seq)
+        rec = self.records[req]
+        rec.finished, rec.tokens = time.perf_counter(), seq
+
+    def _admit(self, reqs: List[int]):
+        now = time.perf_counter()
+        for i in reqs:
+            self.records[i] = RequestRecord(admitted=now)
+
+    def _prefill(self, ids: List[List[int]], toks):
+        """Dispatch the prefill of ``toks``, the padded ``ids``."""
+        B, S = toks.shape
+        real = sum(len(x) for x in ids)
+        self.stats["prefills"] += 1
+        self.stats["prefill_tokens"] += real
+        self.stats["prefill_padded_tokens"] += B * S
+        with TraceAnnotation("serve.prefill", tokens=real, padded=B * S):
+            return self.engine.prefill(toks)
+
+    def _first_tokens(self, reqs: List[int], tok) -> np.ndarray:
+        """``tok`` on the host, the first token of each of ``reqs``."""
+        with TraceAnnotation("serve.wait"):
+            host = np.asarray(tok)
+        now = time.perf_counter()
+        for i in reqs:
+            self.records[i].first_token = now
+        return host
 
     def _run_wave(self, prompts, budgets, queue, results):
         eng = self.engine
         self.stats["waves"] += 1
         B = min(self.batch, len(queue))
         wave, queue = queue[:B], queue[B:]
-        toks, pad_prefix = eng._prepare([prompts[i] for i in wave])
+        with TraceAnnotation("serve.prepare"):
+            ids = [eng._encode(prompts[i]) for i in wave]
+            toks, pad_prefix = eng._pad_batch(ids)
+        self._admit(wave)
         key = jax.random.PRNGKey(eng.ecfg.seed)
-        logits, caches = eng.prefill(toks)
+        logits, caches = self._prefill(ids, toks)
         tok = eng._sample(logits, key)          # first token of every row
         eos = eng.tok.eos_id
         finished = tok == eos
         row_req: List[Optional[int]] = list(wave)
-        first = np.asarray(tok)
+        first = self._first_tokens(wave, tok)
         row_toks: List[List[int]] = [[int(first[r])] for r in range(B)]
 
         while True:
             # finalize satisfied rows (EOS or budget reached) — checked
             # before every chunk, so a budget of 1 / an EOS first token
             # never costs a full decode chunk
-            for r in range(B):
-                if row_req[r] is None:
-                    continue
-                budget = budgets[row_req[r]]
-                ts = row_toks[r]
-                if eos in ts[:budget] or len(ts) >= budget:
-                    self._finalize(row_req[r], ts, budget, results)
-                    row_req[r] = None
+            with TraceAnnotation("serve.finalize"):
+                for r in range(B):
+                    if row_req[r] is None:
+                        continue
+                    budget = budgets[row_req[r]]
+                    ts = row_toks[r]
+                    if eos in ts[:budget] or len(ts) >= budget:
+                        self._finalize(row_req[r], ts, budget, results)
+                        row_req[r] = None
             live = [r for r in range(B) if row_req[r] is not None]
             if not live:
                 break                            # fresh wave is cheaper
             free = [r for r in range(B) if row_req[r] is None]
-            cur = int(caches["_pos"])
+            with TraceAnnotation("serve.wait"):
+                cur = int(caches["_pos"])
             if free and queue and cur < eng.ecfg.max_seq:
-                caches, pad_prefix, tok, finished, queue = self._swap_in(
-                    prompts, budgets, queue, free, cur, caches,
-                    pad_prefix, tok, finished, row_req, row_toks)
+                with TraceAnnotation("serve.swap_in"):
+                    caches, pad_prefix, tok, finished, queue = \
+                        self._swap_in(prompts, budgets, queue, free, cur,
+                                      caches, pad_prefix, tok, finished,
+                                      row_req, row_toks)
                 live = [r for r in range(B) if row_req[r] is not None]
             # rows that stayed free (empty queue / no room): freeze
             idle = [r for r in range(B) if row_req[r] is None]
@@ -507,18 +582,24 @@ class ServeLoop:
                         ceil_align(max_rem))
             if steps <= 0:
                 break                            # cache capacity reached
-            out = eng._fused(steps, start=False, batch=B)(
-                eng.params, tok, caches, pad_prefix, key, finished)
+            with TraceAnnotation("serve.chunk", pos=cur, steps=steps,
+                                 rows=B):
+                out = eng._fused(steps, start=False, batch=B)(
+                    eng.params, tok, caches, pad_prefix, key, finished)
             caches, key = out["caches"], out["key"]
             finished, tok = out["finished"], out["last_tok"]
             self.stats["chunks"] += 1
-            chunk = np.asarray(out["tokens"])
+            self.stats["decode_steps"] += steps
+            self.stats["decode_row_steps"] += steps * B
+            with TraceAnnotation("serve.wait"):
+                chunk = np.asarray(out["tokens"])
             for r in live:
                 row_toks[r].extend(chunk[r].tolist())
-        for r in range(B):
-            if row_req[r] is not None:           # capacity-truncated rows
-                self._finalize(row_req[r], row_toks[r],
-                               budgets[row_req[r]], results)
+        with TraceAnnotation("serve.finalize"):
+            for r in range(B):
+                if row_req[r] is not None:       # capacity-truncated rows
+                    self._finalize(row_req[r], row_toks[r],
+                                   budgets[row_req[r]], results)
         return queue
 
     def _swap_in(self, prompts, budgets, queue, free, cur, caches,
@@ -540,7 +621,7 @@ class ServeLoop:
         for r in free:
             if not queue:
                 break
-            ids = eng.tok.encode(prompts[queue[0]])[: max_seq - ALIGN]
+            ids = eng._encode(prompts[queue[0]])
             fresh_len = max(ALIGN, ceil_align(len(ids)))
             fresh_cap = 1 + max_seq - fresh_len    # tok0 + decode room
             need = min(budgets[queue[0]], fresh_cap)
@@ -552,7 +633,8 @@ class ServeLoop:
         if not rows:
             return caches, pad_prefix, tok, finished, queue
         sub, sub_pp = eng._pad_batch(new_ids, cur)
-        lg_n, c_n = eng.prefill(sub)
+        self._admit(new_reqs)
+        lg_n, c_n = self._prefill(new_ids, sub)
         tok_n = eng._sample(lg_n, jax.random.PRNGKey(
             eng.ecfg.seed + 1 + new_reqs[0]))
         B = int(tok.shape[0])
@@ -561,7 +643,7 @@ class ServeLoop:
         pad_prefix = pad_prefix.at[rows_arr].set(sub_pp)
         tok = tok.at[rows_arr].set(tok_n)
         finished = finished.at[rows_arr].set(tok_n == eng.tok.eos_id)
-        arr_n = np.asarray(tok_n)
+        arr_n = self._first_tokens(new_reqs, tok_n)
         for j, r in enumerate(rows):
             row_req[r] = new_reqs[j]
             row_toks[r] = [int(arr_n[j])]
@@ -569,5 +651,5 @@ class ServeLoop:
         return caches, pad_prefix, tok, finished, queue
 
 
-__all__ = ["Engine", "EngineConfig", "ServeLoop", "scatter_rows", "ALIGN",
-           "ceil_align"]
+__all__ = ["Engine", "EngineConfig", "ServeLoop", "RequestRecord",
+           "SERVE_STATS", "scatter_rows", "ALIGN", "ceil_align"]

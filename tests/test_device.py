@@ -12,8 +12,10 @@ from repro.launch import device, roofline
 @pytest.fixture
 def restore_cache_dir():
     before = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     yield
     jax.config.update("jax_compilation_cache_dir", before)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
 
 
 def test_compile_cache_honours_env(monkeypatch, restore_cache_dir):
@@ -29,6 +31,20 @@ def test_compile_cache_defaults_to_checkout(monkeypatch, restore_cache_dir):
     root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     assert path == os.path.join(root, ".jax_cache")
     assert jax.config.jax_compilation_cache_dir == path
+
+
+@pytest.mark.parametrize("env", [None, "/elsewhere/cache"])
+def test_compile_cache_keys_on_named_scopes(monkeypatch, restore_cache_dir,
+                                            env):
+    """Scopes are metadata: without it in the key, a program would load an
+    entry compiled without its scopes."""
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    device.enable_compile_cache()
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
 
 
 def test_require_tpu_names_the_platform():

@@ -123,8 +123,8 @@ def test_v_residual_group_roundtrip():
 
 
 def test_legacy_cache_ops_bit_identical():
-    """The legacy select/scatter formulations (the decode-throughput
-    benchmark baseline, behind ``legacy=True``) and the predicated-write
+    """The legacy select/scatter formulations (behind ``legacy=True``)
+    and the predicated-write
     / overlay rewrites are pure data-movement variants: bit-identical
     caches and gathers across region boundaries (ring entry, demotion
     start, group commits, partial residual, full cache)."""

@@ -39,8 +39,7 @@ from repro.serving.engine import Engine, EngineConfig, ServeLoop
 pytestmark = pytest.mark.skipif(
     not mesh_available(2, 2),     # every test here builds a 2x2 mesh
     reason="multi-device tier needs >= 4 devices: run under "
-           "XLA_FLAGS=--xla_force_host_platform_device_count=8 "
-           "(8 also covers benchmarks/serve_scaling.py's 4x2 mesh)")
+           "XLA_FLAGS=--xla_force_host_platform_device_count=8")
 
 DENSE_GQA = ModelConfig(name="md-gqa", family="dense", n_layers=2,
                         d_model=128, n_heads=4, n_kv_heads=2, head_dim=32,
