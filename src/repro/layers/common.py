@@ -7,6 +7,7 @@ the fp training path share one code site.
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional, Union
 
 import jax
@@ -107,6 +108,13 @@ WeightLike = Union[jax.Array, QuantizedWeight]
 # The universal linear
 # ---------------------------------------------------------------------------
 
+# Rows (all of x's leading dims) up to which ``qlinear`` multiplies packed
+# INT4 weights without dequantizing them.  On a v5e the per-group form is
+# 10-25x faster at 4-8 rows and 2-3x faster at 1024; from 2048 rows one dot
+# over the dequantized weight wins at some widths (chip readings in PERF.md).
+PACKED_DOT_MAX_ROWS = 1024
+
+
 def qlinear(x: jax.Array, w: WeightLike, quant: Optional[QuantConfig] = None,
             bias: Optional[jax.Array] = None,
             quantize_input: bool = True) -> jax.Array:
@@ -116,12 +124,20 @@ def qlinear(x: jax.Array, w: WeightLike, quant: Optional[QuantConfig] = None,
     * activation BFP: group 32 along the contraction dim (per token).
     * ``w`` may be a raw array (training / fp eval; weight fake-quant is
       applied offline by ``repro.quant.int4.fake_quant_params``) or a packed
-      ``QuantizedWeight`` (serving; dequantized on the fly — on TPU the
-      Pallas ``bfp_matmul`` kernel fuses this; the XLA path here is the
-      portable fallback with identical numerics).
+      ``QuantizedWeight`` (serving).  With at most ``PACKED_DOT_MAX_ROWS``
+      rows (decode, short prefills) the packed weight is never dequantized:
+      its nibbles are contracted per 128-group with the matching
+      activations into float32 partial sums, and the group scales are
+      applied to those sums (``_packed_linear``).  No float weight reaches
+      memory: the dot reads INT4 nibbles.  The products of BFP8 activations
+      and INT4 values are exact in float32, so this rounds less than a dot
+      over a bf16 weight.  With more rows the weight is dequantized once
+      (``weight_dequant``) and multiplied in one dot, which the MXU runs
+      faster than per-group partials.
 
     Its work runs under the named scope ``qlinear``, split into
-    ``act_quant``, ``weight_dequant`` and ``matmul`` (bias included).
+    ``act_quant``, ``weight_dequant`` (nibbles to floats) and ``matmul``
+    (dot, group scales and bias).
     """
     with jax.named_scope("qlinear"):
         if quant is not None and quant.enabled and quant.quant_linear_acts \
@@ -131,6 +147,8 @@ def qlinear(x: jax.Array, w: WeightLike, quant: Optional[QuantConfig] = None,
                                        quant.act_mantissa_bits,
                                        quant.rounding, axis=-1, ste=quant.ste)
         if isinstance(w, QuantizedWeight):
+            if math.prod(x.shape[:-1]) <= PACKED_DOT_MAX_ROWS:
+                return _packed_linear(x, w, bias)
             with jax.named_scope("weight_dequant"):
                 w = weight_dequant(w, x.dtype)
         with jax.named_scope("matmul"):
@@ -138,6 +156,32 @@ def qlinear(x: jax.Array, w: WeightLike, quant: Optional[QuantConfig] = None,
             if bias is not None:
                 y = y + bias.astype(y.dtype)
         return y
+
+
+def _packed_linear(x: jax.Array, qw: QuantizedWeight,
+                   bias: Optional[jax.Array]) -> jax.Array:
+    """x @ weight_dequant(qw) + bias without building the float weight."""
+    ngroups, out_dim = qw.scale.shape
+    half = qw.in_dim // ngroups // 2
+    with jax.named_scope("weight_dequant"):
+        # (G, g/2, out, 2) INT4 values, [..., 0] the low nibble (the even
+        # index along in), sign-extended by the shifts.  Through int4 the
+        # split fuses with the layer scan's weight slice and writes int4
+        # nibbles, which the dot reads; a split fused into the dot makes
+        # the scan copy each layer's packed weight first, slower on v5e.
+        # (A bitcast of the bytes to int4 pairs gives wrong products there.)
+        p = qw.packed.reshape(ngroups, half, out_dim)
+        lo = (jnp.left_shift(p, 4) >> 4).astype(jnp.int4)
+        hi = (p >> 4).astype(jnp.int4)
+        w = jnp.stack([lo, hi], axis=-1).astype(x.dtype)
+    with jax.named_scope("matmul"):
+        xs = x.reshape(x.shape[:-1] + (ngroups, half, 2))
+        part = jnp.einsum("...gkn,gkon->...go", xs, w,
+                          preferred_element_type=jnp.float32)
+        y = jnp.einsum("...go,go->...o", part, qw.scale)
+        if bias is not None:
+            y = y + bias.astype(jnp.float32)
+        return y.astype(x.dtype)
 
 
 def embed_lookup(tokens: jax.Array, table: jax.Array,

@@ -5,7 +5,9 @@ import numpy as np
 import pytest
 
 from repro.configs import get_arch, list_archs
-from repro.layers.common import QuantizedWeight, weight_dequant
+from repro.core import bfp
+from repro.core.quant_config import harmonia
+from repro.layers.common import QuantizedWeight, qlinear, weight_dequant
 from repro.models.config import ModelConfig
 from repro.models.init import init_packed_params, init_params
 from repro.quant.int4 import (fake_quant_params, fake_quant_weight,
@@ -70,3 +72,49 @@ def test_init_packed_params_equals_pack_of_init(arch):
         assert a.shape == b.shape and a.dtype == b.dtype, path
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("bias,quantize_input", [(False, True), (True, True),
+                                                 (False, False)])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("lead", [(3,), (2, 3)])
+@pytest.mark.parametrize("in_dim", [128, 512])
+def test_packed_qlinear_matches_float32_dequant_product(in_dim, lead, dtype,
+                                                        bias, quantize_input):
+    """Packed ``qlinear`` (nibbles contracted per group, scales applied to
+    the float32 partial sums) equals BFP(x) @ weight_dequant(w) computed in
+    float32.  Tolerance: 1e-5 of sum |x||w| + |b| (float32 summation order),
+    plus 2^-8 of |y| for bf16 activations (the output's rounding).  In
+    bf16 it is also no further from that product than the form that
+    dequantizes the weight to bf16 first."""
+    rng = np.random.default_rng(in_dim + len(lead))
+    out_dim = 64
+    qw = quantize_weight(
+        jnp.asarray(rng.normal(size=(in_dim, out_dim)), jnp.float32) * 0.05)
+    x = jnp.asarray(rng.normal(size=lead + (in_dim,)), dtype)
+    b = jnp.asarray(rng.normal(size=(out_dim,)), jnp.float32) * 0.1 \
+        if bias else jnp.zeros((out_dim,), jnp.float32)
+    quant = harmonia(4)
+    got = qlinear(x, qw, quant, bias=b if bias else None,
+                  quantize_input=quantize_input)
+    assert got.shape == lead + (out_dim,) and got.dtype == dtype
+
+    xq = bfp.bfp_fake_quant(x, quant.group_size, quant.act_mantissa_bits,
+                            quant.rounding) if quantize_input else x
+    xf = xq.astype(jnp.float32)
+    wf = weight_dequant(qw, jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    ref = np.asarray(jnp.einsum("...i,io->...o", xf, wf, precision=hi) + b)
+    mag = np.asarray(jnp.einsum("...i,io->...o", jnp.abs(xf), jnp.abs(wf),
+                                precision=hi) + jnp.abs(b))
+    tol = 1e-5 * mag
+    if dtype == jnp.bfloat16:
+        tol = tol + 2.0 ** -8 * np.abs(ref)
+    err = np.abs(np.asarray(got, np.float32) - ref)
+    assert (err <= tol).all(), float((err - tol).max())
+
+    if dtype == jnp.bfloat16:
+        old = jnp.einsum("...i,io->...o", xq, weight_dequant(qw, dtype))
+        old = old + b.astype(dtype)
+        err_old = np.abs(np.asarray(old, np.float32) - ref)
+        assert np.sqrt((err ** 2).mean()) <= np.sqrt((err_old ** 2).mean())

@@ -6,7 +6,9 @@ refuse (unsupported ops, programs that do not fit its memory).  These
 tests compile Llama-3.1-8B's prefill, decode step and one fused
 ``generate_loop`` chunk at full published width from abstract INT4-packed
 parameters, and check that each program's arguments plus temporaries fit
-one chip.  Nothing runs, so they say nothing about speed or results.
+one chip.  They also compile a packed ``qlinear`` at decode widths and
+check that it reads near the packed INT4 bytes and builds no float copy of
+the weight.  Nothing runs, so they say nothing about speed or results.
 
 The topology is described in a fixture, never at import: only one process
 may load the TPU library, and every test worker imports this file.
@@ -21,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_arch
 from repro.core.quant_config import harmonia
+from repro.layers.common import QuantizedWeight, qlinear
 from repro.models import lm
 from repro.models.init import abstract_params
 from repro.quant.int4 import abstract_pack_params
@@ -102,3 +105,24 @@ def test_generate_loop_chunk_compiles_for_v5e(shapes):
     _fits(f.lower(shapes["params"], shapes["row"], shapes["caches"],
                   shapes["row"], shapes["key"],
                   shapes["finished"]).compile())
+
+
+@pytest.mark.parametrize("in_dim,out_dim,rows", [
+    (6144, 24576, 8),      # StarCoder2-15B MLP up, batch 8
+    (24576, 6144, 8),      # StarCoder2-15B MLP down
+    (4096, 102400, 4),     # DeepSeek-LLM-7B head, batch 4
+])
+def test_packed_qlinear_reads_packed_bytes_on_v5e(one_chip, in_dim, out_dim,
+                                                  rows):
+    """A decode-width packed linear moves a few times its packed nibbles and
+    keeps no weight-sized float temporary (a dequantize-then-dot form moves
+    about 69 times and keeps 16 times the packed bytes)."""
+    def on(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    x = on((rows, in_dim), jnp.bfloat16)
+    qw = QuantizedWeight(on((in_dim // 2, out_dim), jnp.int8),
+                         on((in_dim // 128, out_dim), jnp.float32))
+    compiled = jax.jit(partial(qlinear, quant=QUANT)).lower(x, qw).compile()
+    packed = in_dim * out_dim // 2
+    assert compiled.cost_analysis()["bytes accessed"] <= 8 * packed
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * packed
