@@ -7,10 +7,11 @@ For each ``--seeds`` seed the program as the configuration states it,
 and for each ``--control-seeds`` seed the control (the program's own
 lower-precision path: linear inputs, Q, K and fresh V at 4-bit BFP where
 the configuration states 8), each plays the cell's job once at the
-cell's batch and sizes, and the sampled requests are held to the
-reference as in a benchmark run.  One JSON line per seed, then a
-summary: the largest gap of the program's seeds (the lower reading) and
-the smallest of the control's (the upper one).  Everything runs in this
+cell's batch and sizes, on the cell's chips (``run.serve_mesh``), and
+the sampled requests are held to the reference as in a benchmark run.
+One JSON line per seed, then a summary: the largest gap of the program's
+seeds (the lower reading) and the smallest of the control's (the upper
+one).  Everything runs in this
 one process, so the programs compile once.  The benchmark's own runs do
 not run this.
 """
@@ -40,10 +41,10 @@ def seeds(text: str) -> list:
     return out
 
 
-def reading(cell: dict, seed: int, quant=None) -> dict:
+def reading(cell: dict, seed: int, quant=None, mesh=None) -> dict:
     from loop import play
     conf, mix, lim = cell["config"], cell["traffic"], cell["limits"]
-    loop = run.build(conf, mix, seed, quant)
+    loop = run.build(conf, mix, seed, quant, mesh)
     prompts, budgets = traffic.job(mix, seed)
     job = play(loop, prompts, budgets)
     failed = sum(s is None for s in job["served"])
@@ -65,7 +66,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
     bench = spec.load_benchmark()
     cell = spec.cell(bench, args.workload)
-    require_tpu(int(cell["workload"]["chips"]))
+    chips = int(cell["workload"]["chips"])
+    mesh = run.serve_mesh(require_tpu(chips), chips)
     enable_compile_cache()
     control = run.quant_config(dict(cell["config"]["numerics"],
                                     act_bits=CONTROL_ACT_BITS))
@@ -74,7 +76,7 @@ def main(argv=None):
             ("program", seeds(args.seeds), None, lower),
             ("control", seeds(args.control_seeds), control, upper)):
         for seed in seed_list:
-            r = dict(reading(cell, seed, quant), kind=kind)
+            r = dict(reading(cell, seed, quant, mesh), kind=kind)
             out.append(r["max_logit_gap"])
             print(json.dumps(r), flush=True)
     print(json.dumps({"workload": args.workload,

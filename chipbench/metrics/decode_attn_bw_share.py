@@ -1,9 +1,10 @@
 """Share of its bandwidth roofline that decode attention reaches, in %:
 the packed cache bytes of the positions every row holds at every decoded
-step (``layer_counts.chunk_cache_bytes``, from the counter, steps and
-rows that each ``serve.chunk`` span carries) over the HBM rate, over the
-device time of the ops under ``attention`` in the fused decode loop
-(``scopes.py``)."""
+step (``layer_counts.chunk_cache_bytes``, all KV heads, from the counter,
+steps and rows that each ``serve.chunk`` span carries) over the HBM rate
+of the chips that share the cache (``chips`` times one chip's), over the
+device time of the ops under ``attention`` that run no collective, in
+the fused decode loop (``scopes.py``, averaged over the chips)."""
 import layer_counts
 import scopes
 
@@ -17,4 +18,5 @@ def read(ctx):
         return None
     total = sum(layer_counts.chunk_cache_bytes(
         ctx["model"], c["pos"], c["steps"], c["rows"]) for c in r["chunks"])
-    return 100.0 * total / ctx["peaks"]["hbm_bytes_per_s"] / s
+    hbm = ctx["chips"] * ctx["peaks"]["hbm_bytes_per_s"]
+    return 100.0 * total / hbm / s

@@ -1,8 +1,8 @@
 """Device time of attention in one decode step, in ms: the ops under the
 program's ``attention`` scope (rotary embedding, the cache append, the
-cache gather and the scores, softmax and weighted sum) in the fused
-decode loop, over the decode steps the traced job's ``serve.chunk``
-spans carry (``scopes.py``)."""
+cache gather and the scores, softmax and weighted sum; collectives left
+out) in the fused decode loop, over the decode steps the traced job's
+``serve.chunk`` spans carry (``scopes.py``)."""
 import scopes
 
 

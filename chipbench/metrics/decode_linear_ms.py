@@ -1,7 +1,8 @@
 """Device time of the linear layers in one decode step, in ms: the ops
 under the program's ``qlinear`` scope (activation quantization, weight
-dequantization and the multiply) in the fused decode loop, over the
-decode steps the traced job's ``serve.chunk`` spans carry
+dequantization and the multiply; on a mesh, not the collectives under
+it, which are ``decode_collective_ms``') in the fused decode loop, over
+the decode steps the traced job's ``serve.chunk`` spans carry
 (``scopes.py``)."""
 import scopes
 

@@ -1,5 +1,6 @@
 """Packed weight bytes one decode step must read (``counts.
-decode_weight_bytes``) over the chip's HBM bandwidth and the measured
+decode_weight_bytes``, the whole model) over the HBM bandwidth of the
+chips that share them (``chips`` times one chip's) and the measured
 decode step, in %.  A lower bound on the step's share of its bandwidth
 roofline: the cache's bytes are left out."""
 
@@ -9,5 +10,6 @@ def read(ctx):
     steps = ctx["job"]["decode_steps"]
     if not ex["count"] or not steps or ex["seconds"] <= 0:
         return None
-    floor_s = ctx["job"]["decode_weight_bytes"] / ctx["peaks"]["hbm_bytes_per_s"]
+    floor_s = ctx["job"]["decode_weight_bytes"] / (
+        ctx["chips"] * ctx["peaks"]["hbm_bytes_per_s"])
     return 100.0 * floor_s / (ex["seconds"] / steps)

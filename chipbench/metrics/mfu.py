@@ -1,6 +1,7 @@
 """Model FLOP/s utilization of the traced job, in %: the operations the
 job's real prompt and served tokens need (``counts.request_flops``) over
-the traced window times the chip's peak bf16 rate."""
+the traced window times the peak bf16 rate of the chips that share the
+work (``chips`` times one chip's)."""
 
 
 def read(ctx):
@@ -8,4 +9,4 @@ def read(ctx):
     if window <= 0 or not ctx["job"]["flops"]:
         return None
     return 100.0 * ctx["job"]["flops"] / (
-        window * ctx["peaks"]["bf16_flops_per_s"])
+        window * ctx["chips"] * ctx["peaks"]["bf16_flops_per_s"])

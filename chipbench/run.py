@@ -11,7 +11,11 @@ A run, in order:
    call (with the reference's draw of the norm and bias parameters in
    place of the initializer's ones and zeros), and builds the ``Engine``
    (XLA path, greedy) and a recording ``ServeLoop`` at the mix's batch
-   and ``max_seq``;
+   and ``max_seq``.  A cell of more than one chip serves on a (data=1,
+   model=chips) mesh of them, with the program's own layer-by-layer draw
+   (``models/init.py:init_packed_params(mesh=)``): each layer is drawn
+   whole on the first chip and placed in its tensor-parallel sharding
+   (``distributed/sharding.py:param_pspecs``) before the next is drawn;
 3. makes the job from the seed (``traffic.py``) and plays it once to warm
    every program the job uses;
 4. ``--trace 0``: plays the job back to back for ``--seconds`` (whole
@@ -131,32 +135,59 @@ def put_affine(params: dict, affine: dict) -> dict:
     return out
 
 
+def serve_mesh(devices, chips: int):
+    """The mesh a cell of ``chips`` chips serves on: (data=1,
+    model=chips) over the first ``chips`` of ``devices``, tensor-parallel
+    on ``model``.  ``None`` for one chip: the engine's single-device
+    path."""
+    if chips == 1:
+        return None
+    if len(devices) < chips:
+        raise ValueError(f"the cell needs {chips} devices, have "
+                         f"{len(devices)}")
+    from jax.sharding import Mesh
+    return Mesh(np.asarray(devices[:chips]).reshape(1, chips),
+                ("data", "model"))
+
+
 @lru_cache(maxsize=None)
-def _init_weights(cfg, reference: str, model: tuple):
+def _init_weights(cfg, reference: str, model: tuple, mesh=None):
     import jax
     from repro.models.init import init_packed_params
     affine = spec.reference(reference).affine_params
+    if mesh is None:
+        def init(key):
+            return put_affine(init_packed_params(cfg, key),
+                              affine(dict(model), key))
+        return jax.jit(init)
 
-    def init(key):
-        return put_affine(init_packed_params(cfg, key),
-                          affine(dict(model), key))
-    return jax.jit(init)
+    def init_sharded(key):
+        # the program's own draw on a mesh: each layer drawn and packed,
+        # then placed in its shards before the next is drawn
+        params = init_packed_params(cfg, key, mesh=mesh)
+        return jax.jit(lambda p, k: put_affine(p, affine(dict(model), k)),
+                       out_shardings=jax.tree.map(lambda x: x.sharding,
+                                                  params),
+                       donate_argnums=0)(params, key)
+    return init_sharded
 
 
-def build(conf: dict, mix: dict, seed: int, quant=None):
-    """Weights (on the device, from the seed, in one jitted call), the
-    engine and the recording loop."""
+def build(conf: dict, mix: dict, seed: int, quant=None, mesh=None):
+    """Weights (from the seed: on the device in one jitted call, or on
+    ``mesh`` layer by layer into their shards), the engine and the
+    recording loop."""
     import jax
     from loop import RecordingServeLoop
     from repro.serving.engine import Engine, EngineConfig
     cfg = model_config(conf)
     quant = quant_config(conf["numerics"]) if quant is None else quant
     params = _init_weights(cfg, conf["reference"],
-                           tuple(sorted(conf["model"].items())))(
+                           tuple(sorted(conf["model"].items())), mesh)(
                                seed_key(seed))
     jax.block_until_ready(params)
     engine = Engine(params, cfg, EngineConfig(
-        max_seq=int(mix["max_seq"]), quant=quant, sampler="greedy"))
+        max_seq=int(mix["max_seq"]), quant=quant, sampler="greedy",
+        mesh=mesh))
     return RecordingServeLoop(engine, int(mix["batch_size"]), MAX_STEPS)
 
 
@@ -216,11 +247,26 @@ def check(conf: dict, mix: dict, lim: dict, seed: int, prompts, jobs,
             (len(requests), n_tok))
 
 
+def layer_ctx(reduced: dict, numbers: dict, conf: dict, mix: dict,
+              device_kind: str, chips: int) -> dict:
+    """What each per-layer metric's reader is handed: the reduced trace,
+    the job's numbers, the configuration's sizes, the mix, one chip's
+    peak rates and the number of chips that share the work (rooflines of
+    shared work are ``chips`` times one chip's).  Raises where the trace
+    holds another number of chips than the cell runs on."""
+    if reduced["chips"] != chips:
+        raise ValueError(f"the trace holds {reduced['chips']} TPU planes; "
+                         f"the cell runs on {chips} chips")
+    return {"trace": reduced, "job": numbers, "model": conf["model"],
+            "mix": mix, "peaks": counts.peaks(device_kind), "chips": chips}
+
+
 def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
              trace: bool, devices=None, log=None, quant=None) -> dict:
-    """One run.  ``devices`` skips the look for a chip, and ``quant``
-    serves with other numerics than the configuration states (the
-    harness's tests and ``control.py``)."""
+    """One run.  ``devices`` skips the look for a chip (the cell serves on
+    the first ``chips`` of them), and ``quant`` serves with other
+    numerics than the configuration states (the harness's tests and
+    ``control.py``)."""
     import jax
     from loop import play
     from repro.launch.device import enable_compile_cache, require_tpu
@@ -233,8 +279,10 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
         enable_compile_cache()
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    chips = int(w["chips"])
+    devices = devices[:chips]
     counter = CompileCounter()
-    loop = build(conf, mix, seed, quant)
+    loop = build(conf, mix, seed, quant, serve_mesh(devices, chips))
     log(f"weights and engine ready at {time.perf_counter() - T_START:.1f} s")
     prompts, budgets = traffic.job(mix, seed)
     play(loop, prompts, budgets)                       # warm pass
@@ -259,6 +307,7 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
     window_s = time.perf_counter() - t0
     if trace:
         jax.profiler.stop_trace()
+        log(f"trace written at {time.perf_counter() - T_START:.1f} s")
     counter.on = False
     log(f"compiles_in_window {counter.n}")
     log(f"window: {len(jobs)} jobs in {window_s:.3f} s")
@@ -281,17 +330,22 @@ def run_cell(bench: dict, cell: dict, seed: int, seconds: float,
 
     if trace:
         import trace as trace_lib
+        t_red = time.perf_counter()
         reduced = trace_lib.reduce_dir(TRACE_DIR)
+        log(f"trace of {os.path.getsize(trace_lib.find_xplane(TRACE_DIR))} "
+            f"bytes reduced in {time.perf_counter() - t_red:.1f} s")
         log("executables traced (s): " + ", ".join(
             f"{k} {v:.4f}" for k, v in sorted(
                 reduced["modules"].items(), key=lambda kv: -kv[1])[:8]))
-        ctx = {"trace": reduced, "job": numbers, "model": conf["model"],
-               "mix": mix, "peaks": counts.peaks(dev.device_kind)}
+        ctx = layer_ctx(reduced, numbers, conf, mix, dev.device_kind, chips)
         metrics = {}
         for m in bench["per_layer"]:
             value = spec.metric_reader(m["name"])(ctx)
             if value is not None:
                 metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        trace_lib.load.cache_clear()           # the trace's events, read
+        trace_lib.device_planes.cache_clear()  # once for all the readers
+        log(f"per-layer metrics read at {time.perf_counter() - T_START:.1f} s")
         device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
         breakdown = {"device_ops": reduced["device_ops"],
                      "idle_gaps": reduced["idle_gaps"]}

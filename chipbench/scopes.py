@@ -5,7 +5,10 @@
 Device side: the self time of every op of the ``XLA Ops`` line inside
 the traced job (the harness's host span ``job``), summed by executable
 and by the path of the program's named scopes (``SCOPES``) in the op's
-``op_name`` metadata, outermost first (``qlinear/matmul``).  A TPU
+``op_name`` metadata, outermost first (``qlinear/matmul``), and the
+self time of the collective ops (``COLLECTIVES``, with their async
+``-start`` and ``-done`` halves) by executable, collective and scope
+path.  A TPU
 trace's op events carry no ``op_name``: it is read from the HLO text of
 the executable the op ran in, keyed by executable and op name.  The
 profiler keeps each executable's compiled HLO (the text
@@ -36,7 +39,6 @@ import re
 import sys
 from collections import defaultdict
 from functools import lru_cache
-from typing import NamedTuple
 
 import trace as trace_lib
 
@@ -50,6 +52,8 @@ STACK = "layers"  # the layer scan: every block's work runs inside it
 SCOPES = ("embed", STACK, "norm", "qlinear", "act_quant", "weight_dequant",
           "matmul", "attention", "kv_gather", "attend", "kv_append",
           "kv_convert", "mlp_act", "residual", "head", "sample")
+COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter",
+               "collective-permute", "all-to-all")
 TOP_OPS = 5       # ops listed per executable and scope path
 FOLLOW = 8        # operand links followed to find an unscoped op's scope
 
@@ -58,6 +62,14 @@ FOLLOW = 8        # operand links followed to find an unscoped op's scope
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = "
                     r"(?:\((?:[^()]|\([^()]*\))*\)|\S+) [\w\-]+\((.*)$")
 _META_OP_NAME = re.compile(r'metadata=\{[^}]*?op_name="([^"]*)"')
+# an op's opcode in its instruction text; a collective's op name or
+# opcode starts with its kind (``all-reduce.3``, ``all-gather-start.1``,
+# ``all-reduce-done``, a fusion named after the collective it holds)
+_OPCODE = re.compile(r" = (?:\((?:[^()]|\([^()]*\))*\)|\S+) ([\w\-]+)\(")
+_COLLECTIVE = re.compile("|".join(COLLECTIVES))
+# a computation's first line, and the computation an op calls
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{\s*$")
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
 
 
 def scope_path(op_name: str) -> tuple:
@@ -169,17 +181,54 @@ def _resolve(ops: dict, name: str) -> tuple:
     return ()
 
 
+def hlo_collectives(text: str) -> dict:
+    """{op name: collective kind} of the ops of an HLO module's text that
+    run a collective: a collective op, or an op that calls a computation
+    holding one.  (On v5e an all-reduce whose result each chip keeps a
+    slice of is a ``kCustom`` fusion, named ``fusion.N``, that calls an
+    ``all-reduce-scatter`` computation.)"""
+    held, calls, out = {}, {}, {}
+    comp = None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            comp = head.group(1)
+            continue
+        got = _split_instr(line)
+        op = _OPCODE.search(line)
+        if not got or not op:
+            continue
+        kind = _COLLECTIVE.match(op.group(1))
+        if kind:
+            out[got[0]] = held[comp] = kind.group(0)
+        callee = _CALLS.search(line)
+        if callee:
+            calls[got[0]] = (comp, callee.group(1))
+    for _ in range(FOLLOW):             # callers of callers, a few deep
+        new = {name: held[callee] for name, (_, callee) in calls.items()
+               if callee in held and name not in out}
+        if not new:
+            break
+        out.update(new)
+        for name in new:
+            held.setdefault(calls[name][0], new[name])
+    return out
+
+
+def collective(event_name: str):
+    """The collective (one of ``COLLECTIVES``) an ``XLA Ops`` event runs,
+    read from its op name or from the opcode in its instruction text, or
+    ``None``."""
+    m = _COLLECTIVE.match(_short(event_name))
+    if m is None:
+        op = _OPCODE.search(event_name)
+        m = op and _COLLECTIVE.match(op.group(1))
+    return m.group(0) if m else None
+
+
 def _short(event_name: str) -> str:
     """``%fusion.3 = bf16[8]{0} fusion(...)`` -> ``fusion.3``."""
     return event_name.split("=")[0].strip().lstrip("%").split(" ")[0]
-
-
-class _Op(NamedTuple):
-    """An ``XLA Ops`` event as ``trace._self_times`` takes it, named by
-    the event itself."""
-    name: object
-    start_ns: float
-    end_ns: float
 
 
 def _program_spans(pd):
@@ -207,39 +256,57 @@ def _args(spans, name, keys):
 def reduce(pd, texts: dict) -> dict:
     """Numbers of the traced job in a ``jax.profiler.ProfileData``, whose
     executables' HLO texts are ``texts`` (``hlo_texts``)."""
+    with trace_lib.no_gc():
+        return _reduce(pd, texts)
+
+
+def _reduce(pd, texts: dict) -> dict:
     tables = {k: hlo_ops(t) for k, t in texts.items()}
+    coll_tables = {k: hlo_collectives(t) for k, t in texts.items()}
     spans = _program_spans(pd)
     windows = [s for s in spans if s[0] == WINDOW_SPAN]
-    devices = [p for p in pd.planes if p.name.startswith("/device:TPU:")]
-    if not windows or not devices:
+    n = sum(p.name.startswith("/device:TPU:") for p in pd.planes)
+    if not windows or not n:
         raise ValueError(f"trace holds {len(windows)} '{WINDOW_SPAN}' "
-                         f"spans and {len(devices)} TPU planes")
+                         f"spans and {n} TPU planes")
     _, w0, w1, _ = windows[0]
     spans = [s for s in spans if s[0] != WINDOW_SPAN
              and s[1] < w1 and s[2] > w0]
 
     scopes = defaultdict(lambda: defaultdict(float))   # module -> path -> ns
     ops = defaultdict(float)                  # (module, path, op) -> ns
-    for plane in devices:
-        lines = {line.name: list(line.events) for line in plane.lines}
+    colls = defaultdict(float)                # (module, kind, path) -> ns
+    named = {}     # (executable, event name) -> (module, path, op, kind)
+    for lines, self_times in trace_lib.device_planes(pd, w0, w1):
         mods = sorted((ev.start_ns, ev.end_ns, ev.name.strip())
                       for ev in lines.get("XLA Modules", []))
-        for ev, t in trace_lib._self_times(
-                [_Op(ev, ev.start_ns, ev.end_ns)
-                 for ev in lines.get("XLA Ops", [])], w0, w1):
+        for ev, t in self_times:
             if t > 0:
                 exe = _enclosing(mods, ev) or ""
-                path = "/".join(_resolve(tables.get(exe, {}),
-                                         _short(ev.name)))
-                module = trace_lib._exec_name(exe)
+                name = ev.name
+                got = named.get((exe, name))
+                if got is None:        # an op runs once a layer and step
+                    got = named[exe, name] = (
+                        trace_lib._exec_name(exe),
+                        "/".join(_resolve(tables.get(exe, {}),
+                                          _short(name))),
+                        trace_lib._op_name(name),
+                        collective(name) or coll_tables.get(exe, {}).get(
+                            _short(name)))
+                module, path, op, kind = got
                 scopes[module][path] += t
-                ops[module, path, trace_lib._op_name(ev.name)] += t
+                ops[module, path, op] += t
+                if kind:
+                    colls[module, kind, path] += t
 
-    n, ns = len(devices), 1e-9
+    ns = 1e-9
     waves = [s for s in spans if s[0] == "serve.wave"]
     waits = trace_lib._union([(s[1], s[2]) for s in spans
                               if s[0] == WAIT_SPAN])
     sched = sum(s[2] - s[1] - _overlap(waits, s[1], s[2]) for s in waves)
+    by_kind = defaultdict(lambda: defaultdict(dict))
+    for (m, kind, p), t in colls.items():
+        by_kind[m][kind][p] = t / n * ns
     top = defaultdict(lambda: defaultdict(list))
     for (m, p, op), t in sorted(ops.items(), key=lambda kv: -kv[1]):
         if len(top[m][p]) < TOP_OPS:
@@ -249,6 +316,7 @@ def reduce(pd, texts: dict) -> dict:
         "scopes": {m: {p: t / n * ns for p, t in d.items()}
                    for m, d in scopes.items()},
         "top_ops": {m: dict(d) for m, d in top.items()},
+        "collectives": {m: dict(d) for m, d in by_kind.items()},
         "spans": {name: sum(s[2] - s[1] for s in spans if s[0] == name) * ns
                   for name in sorted({s[0] for s in spans})},
         "sched_host_s": sched * ns if waves else None,
@@ -280,12 +348,25 @@ def _paths(reduced: dict, modules) -> dict:
 
 def scoped_seconds(reduced: dict, modules, scope: str):
     """Device seconds, in the executables ``modules``, of the ops under
-    ``scope`` (its children included); ``None`` where those executables
-    ran no op under any of the program's scopes."""
+    ``scope`` (its children included) that run no collective (those are
+    ``collective_seconds``'); ``None`` where those executables ran no op
+    under any of the program's scopes."""
     paths = _paths(reduced, modules)
     if not any(p for p in paths):
         return None
-    return sum(t for p, t in paths.items() if scope in p.split("/"))
+    coll = sum(t for m in modules
+               for by_path in reduced["collectives"].get(m, {}).values()
+               for p, t in by_path.items() if scope in p.split("/"))
+    return sum(t for p, t in paths.items() if scope in p.split("/")) - coll
+
+
+def collective_seconds(reduced: dict, modules):
+    """Device seconds of the collective ops in the executables
+    ``modules``; ``None`` where those executables ran none."""
+    total = sum(t for m in modules
+                for paths in reduced["collectives"].get(m, {}).values()
+                for t in paths.values())
+    return total or None
 
 
 def coverage(reduced: dict, modules) -> dict:
@@ -307,8 +388,7 @@ def coverage(reduced: dict, modules) -> dict:
 
 @lru_cache(maxsize=4)
 def _read(path: str, mtime_ns: int):
-    from jax.profiler import ProfileData
-    return reduce(ProfileData.from_file(path), hlo_texts(path))
+    return reduce(trace_lib.load(path, mtime_ns), hlo_texts(path))
 
 
 def read_dir(trace_dir: str = TRACE_DIR):

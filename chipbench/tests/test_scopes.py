@@ -193,10 +193,11 @@ def test_linear_bytes_are_the_weights_less_norms_and_tied_head(config):
         counts.decode_weight_bytes(m) - norms - tied
 
 
-def _ctx(window_s=1000e-9):
+def _ctx(window_s=1000e-9, chips=1):
     """What the harness hands a reader: the readers here take nothing of
     the harness's own job counts."""
-    return {"model": TOY, "peaks": PEAKS, "trace": {"window_s": window_s}}
+    return {"model": TOY, "peaks": PEAKS, "trace": {"window_s": window_s},
+            "chips": chips}
 
 
 READS = {
@@ -260,3 +261,155 @@ def test_new_metrics_are_listed_with_their_cells():
     for name in READS:
         assert listed[name]["moves"] == "output_tok_s"
         assert listed[name]["workloads"] == cells
+
+
+def mesh_xspace():
+    """One job [0, 1000) ns on two chips.  On each, the decode executable
+    ``jit_f(9)`` runs [500, 900): a matmul, an all-reduce after it (an op
+    named by its instruction text), the sampler's all-gather as async
+    halves, and a fusion that calls an all-reduce-scatter computation (as
+    v5e runs a row-shard all-reduce); chip 1's collectives take twice as
+    long.  Host: one chunk of 32 steps."""
+    names = {1: "jit_f(9)",
+             2: "%fusion.1 = f32[128,16]{1,0} fusion(%p)",
+             3: "%all-reduce.2 = f32[128,16]{1,0} all-reduce(f32[128,16]{1,0} "
+                "%fusion.1), channel_id=1, to_apply=%add",
+             4: "all-gather-start.3", 5: "all-gather-done.3",
+             6: "%add.4 = f32[16]{0} add(%a, %b)",
+             7: "%fusion.5 = f32[32,16]{1,0} fusion(%fusion.1), kind=kCustom"}
+    planes = []
+    for chip, k in ((0, 1), (1, 2)):
+        ops = [(2, 500, 600, []), (3, 600, 600 + 40 * k, []),
+               (4, 700, 700 + 10 * k, []), (6, 750, 800, []),
+               (5, 800, 800 + 20 * k, []), (7, 850, 850 + 15 * k, [])]
+        planes.append(_plane(chip + 1, f"/device:TPU:{chip}", [
+            _line(1, "XLA Modules", [(1, 500, 900, [])]),
+            _line(2, "XLA Ops", ops)], names, {}))
+    host = _plane(9, "/host:CPU", [_line(1, "python", [
+        (1, 0, 1000, []), (2, 0, 1000, []),
+        (3, 450, 470, [(1, 0), (2, 32), (3, 16)])])],
+        {1: "job", 2: "serve.wave", 3: "serve.chunk"},
+        {1: "pos", 2: "steps", 3: "rows"})
+    text = "\n".join(planes + [host])
+    return ProfileData.from_serialized_xspace(
+        ProfileData.text_proto_to_serialized_xspace(text))
+
+
+MESH_TEXTS = {"jit_f(9)": "HloModule jit_f\n\n"
+              "%all-reduce-scatter.clone (input.4: f32[128,16]) -> f32[32,16] {\n"
+              "  %input.4 = f32[128,16]{1,0} parameter(0)\n"
+              "  ROOT %all-reduce.9 = f32[128,16]{1,0} all-reduce(%input.4)\n"
+              "}\n\n"
+              "ENTRY %main.2 (p: f32[16]) -> f32[16] {\n"
+              "  %fusion.1 = f32[128,16]{1,0} fusion(), metadata={op_name="
+              "\"jit(f)/while/body/layers/qlinear/matmul/dot_general\"}\n"
+              "  %all-reduce.2 = f32[128,16]{1,0} all-reduce(%fusion.1)\n"
+              "  %all-gather-start.3 = (f32[16,8]{1,0}, f32[16,16]{1,0}) "
+              "all-gather-start(), metadata={op_name="
+              "\"jit(f)/while/body/squeeze\"}\n"
+              "  %fusion.5 = f32[32,16]{1,0} fusion(%fusion.1), kind=kCustom, "
+              "calls=%all-reduce-scatter.clone\n}\n"}
+
+
+@pytest.mark.parametrize("name,kind", [
+    ("all-reduce.2", "all-reduce"), ("all-gather-start.3", "all-gather"),
+    ("all-reduce-done", "all-reduce"), ("reduce-scatter.1", "reduce-scatter"),
+    ("collective-permute-start.4", "collective-permute"),
+    ("all-to-all.5", "all-to-all"),
+    ("%x.7 = (s32[8]{0:T(256)S(1)}, s32[8]) all-gather-start(%a)",
+     "all-gather"),
+    ("%fusion.3 = bf16[8]{0} fusion(%all-reduce.3)", None),
+    ("%reduce.6 = f32[8]{0} reduce(%a, %b)", None), ("copy.4", None)])
+def test_collectives_by_op_name_or_opcode(name, kind):
+    assert scopes.collective(name) == kind
+
+
+def test_collectives_by_kind_and_scope_averaged_over_chips():
+    r = scopes.reduce(mesh_xspace(), MESH_TEXTS)
+    # chip 0: all-reduce 40 and the fusion that calls one 15, gather
+    # halves 10 + 20; chip 1: twice that
+    assert r["collectives"]["jit_f"] == {
+        "all-reduce": {"layers/qlinear/matmul": pytest.approx(82.5e-9)},
+        "all-gather": {"": pytest.approx(45e-9)}}
+    assert scopes.collective_seconds(r, ["jit_f"]) == \
+        pytest.approx(127.5e-9)
+    assert scopes.collective_seconds(r, ["jit__lambda"]) is None
+
+
+def test_collectives_in_the_computations_an_op_calls():
+    text = "\n".join([
+        "HloModule m", "",
+        "%ar (a: f32[8]) -> f32[8] {",
+        "  %a = f32[8]{0} parameter(0)",
+        "  ROOT %all-reduce.1 = f32[8]{0} all-reduce(%a), to_apply=%add",
+        "}", "",
+        "%outer (b: f32[8]) -> f32[8] {",
+        "  %b = f32[8]{0} parameter(0)",
+        "  ROOT %fusion.2 = f32[8]{0} fusion(%b), kind=kCustom, calls=%ar",
+        "}", "",
+        "%plain (c: f32[8]) -> f32[8] {",
+        "  %c = f32[8]{0} parameter(0)",
+        "  ROOT %add.3 = f32[8]{0} add(%c, %c)",
+        "}", "",
+        "ENTRY %main (p: f32[8]) -> f32[8] {",
+        "  %p = f32[8]{0} parameter(0)",
+        "  %fusion.4 = f32[8]{0} fusion(%p), kind=kLoop, calls=%plain",
+        "  %fusion.5 = f32[8]{0} fusion(%fusion.4), kind=kCustom, "
+        "calls=%outer",
+        "  ROOT %all-gather-start.6 = (f32[8]{0}, f32[32]{0}) "
+        "all-gather-start(%fusion.5), dimensions={0}",
+        "}"])
+    assert scopes.hlo_collectives(text) == {
+        "all-reduce.1": "all-reduce", "fusion.2": "all-reduce",
+        "fusion.5": "all-reduce", "all-gather-start.6": "all-gather"}
+
+
+def test_decode_collective_ms_on_a_recorded_mesh_trace(monkeypatch):
+    monkeypatch.setattr(scopes, "read_dir",
+                        lambda: scopes.reduce(mesh_xspace(), MESH_TEXTS))
+    monkeypatch.setattr(scopes, "decode_modules", lambda: ["jit_f"])
+    read = spec.metric_reader("decode_collective_ms")
+    assert read(_ctx(chips=2)) == pytest.approx(1e3 * 127.5e-9 / 32)
+    assert read(_ctx(window_s=2000e-9, chips=2)) is None
+
+
+def test_decode_collective_ms_reads_nothing_on_one_chip(monkeypatch):
+    monkeypatch.setattr(scopes, "read_dir",
+                        lambda: scopes.reduce(recorded(), TEXTS))
+    monkeypatch.setattr(scopes, "decode_modules", lambda: ["jit_f"])
+    assert spec.metric_reader("decode_collective_ms")(_ctx()) is None
+
+
+def test_decode_collective_ms_is_listed_for_the_four_chip_cells():
+    """Listed for every cell of more than one chip and for no other; the
+    reader is ready for the first such cell."""
+    bench = spec.load_benchmark()
+    mesh_cells = [w["name"] for w in bench["workloads"] if w["chips"] > 1]
+    listed = [m for m in bench["per_layer"]
+              if m["name"] == "decode_collective_ms"]
+    assert [m["workloads"] for m in listed] == (
+        [mesh_cells] if mesh_cells else [])
+    for m in listed:
+        assert m["moves"] == "output_tok_s" and m["layer"] == "collectives"
+    assert callable(spec.metric_reader("decode_collective_ms"))
+
+
+def test_scoped_seconds_leave_the_collectives_out():
+    """On a mesh the row-shard all-reduces fused under ``qlinear/matmul``
+    are ``collective_seconds``', not the linears': only the matmul's 100
+    ns a chip stays."""
+    r = scopes.reduce(mesh_xspace(), MESH_TEXTS)
+    assert r["scopes"]["jit_f"]["layers/qlinear/matmul"] == \
+        pytest.approx(182.5e-9)
+    assert scopes.scoped_seconds(r, ["jit_f"], "qlinear") == \
+        pytest.approx(100e-9)
+
+
+@pytest.mark.parametrize("scope", ["qlinear", "attention", "kv_gather"])
+def test_scoped_seconds_on_one_chip_are_the_plain_sum(scope):
+    """Where no collective ran, a scope's seconds are the same float sum
+    as before collectives were left out."""
+    r = scopes.reduce(recorded(), TEXTS)
+    assert r["collectives"] == {}
+    assert scopes.scoped_seconds(r, ["jit_f"], scope) == sum(
+        t for p, t in r["scopes"]["jit_f"].items() if scope in p.split("/"))

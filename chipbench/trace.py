@@ -20,14 +20,19 @@ Times are averaged over the TPU planes found (one per chip used).
 """
 from __future__ import annotations
 
+import gc
 import glob
 import json
 import os
 from collections import defaultdict
+from contextlib import contextmanager
+from functools import lru_cache
+from typing import NamedTuple
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 WINDOW_SPAN = "job"
 HOST_SPANS = ("job", "prefill", "chunk")
+DEVICE_LINES = ("XLA Ops", "XLA Modules")     # the lines of a TPU plane read
 TOP = 10
 
 
@@ -35,6 +40,54 @@ def executables() -> dict:
     """Kind (``prefill``, ``decode_loop``) -> executable names."""
     with open(os.path.join(HERE, "executables.json")) as f:
         return {k: v for k, v in json.load(f).items() if k != "about"}
+
+
+class Event(NamedTuple):
+    """An event of a trace line, read out of the profiler's object once
+    (each read of a profiler event's field crosses into C++)."""
+    name: str
+    start_ns: float
+    end_ns: float
+
+
+@contextmanager
+def no_gc():
+    """The cyclic garbage collector off: a trace is read into millions of
+    tuples, none of them in a cycle, which its passes would walk again and
+    again (more than half the time of a reduction)."""
+    was = gc.isenabled()
+    gc.disable()
+    try:
+        yield
+    finally:
+        if was:
+            gc.enable()
+
+
+@lru_cache(maxsize=1)
+def load(path: str, mtime_ns: int):
+    """The ``jax.profiler.ProfileData`` of the trace file ``path`` (at
+    its version ``mtime_ns``), read once for both reductions of it."""
+    from jax.profiler import ProfileData
+    return ProfileData.from_file(path)
+
+
+@lru_cache(maxsize=1)
+def device_planes(pd, w0, w1) -> list:
+    """Each TPU plane of ``pd`` read out once for both reductions of it:
+    ``({line name: [Event]}, [(event, self time)])``, the self times (in
+    the window [w0, w1)) of its ``XLA Ops`` events.  A four-chip job's
+    trace holds millions of op events."""
+    out = []
+    with no_gc():
+        for plane in pd.planes:
+            if plane.name.startswith("/device:TPU:"):
+                lines = {line.name: [Event(ev.name, ev.start_ns, ev.end_ns)
+                                     for ev in line.events]
+                         for line in plane.lines if line.name in DEVICE_LINES}
+                out.append((lines, _self_times(lines.get("XLA Ops", []),
+                                               w0, w1)))
+    return out
 
 
 def find_xplane(trace_dir: str) -> str:
@@ -72,25 +125,41 @@ def _op_name(event_name: str) -> str:
 
 
 def _self_times(events, w0, w1):
-    """(name, self time in the window) of each event of one line, less the
-    time of the events nested in it."""
+    """(event, self time in the window) of each ``Event`` of one line,
+    less the time of the events nested in it."""
+    evs = sorted(events, key=lambda ev: (ev[1], -ev[2]))
     out = []
-    stack = []                       # [end, index in out]
-    for ev in sorted(events, key=lambda ev: (ev.start_ns, -ev.end_ns)):
-        while stack and stack[-1][0] <= ev.start_ns:
-            stack.pop()
-        s, e = _clip(ev.start_ns, ev.end_ns, w0, w1)
-        dur = max(e - s, 0.0)
-        if stack and ev.end_ns <= stack[-1][0]:       # nested, not overlapping
-            out[stack[-1][1]][1] -= dur
-        out.append([ev.name, dur])
-        stack.append([ev.end_ns, len(out) - 1])
-    return out
+    ends, idx = [], []               # the stack of open events
+    for _, start, end in evs:
+        while ends and ends[-1] <= start:
+            ends.pop()
+            idx.pop()
+        dur = (end if end < w1 else w1) - (start if start > w0 else w0)
+        if dur < 0.0:
+            dur = 0.0
+        if ends and end <= ends[-1]:            # nested, not overlapping
+            out[idx[-1]] -= dur
+        ends.append(end)
+        idx.append(len(out))
+        out.append(dur)
+    return list(zip(evs, out))
+
+
+def _gap_name(spans, s, e):
+    """Name of the innermost of ``spans`` open at the middle of the idle
+    gap [s, e), or ``outside``."""
+    mid = (s + e) / 2
+    open_ = [sp for sp in spans if sp[1] <= mid <= sp[2]]
+    return min(open_, key=lambda sp: sp[2] - sp[1])[0] if open_ else "outside"
 
 
 def reduce(pd, execs: dict = None) -> dict:
     """Numbers of one traced window from a ``jax.profiler.ProfileData``."""
-    execs = executables() if execs is None else execs
+    with no_gc():
+        return _reduce(pd, executables() if execs is None else execs)
+
+
+def _reduce(pd, execs: dict) -> dict:
     spans = []
     devices = []
     for plane in pd.planes:
@@ -111,17 +180,21 @@ def reduce(pd, execs: dict = None) -> dict:
     exec_s = defaultdict(float)
     exec_n = defaultdict(int)
     op_s = defaultdict(float)
+    op_names = {}
     modules = defaultdict(float)
     gaps = []
-    for plane in devices:
-        lines = {line.name: list(line.events) for line in plane.lines}
+    for lines, self_times in device_planes(pd, w0, w1):
         ops = lines.get("XLA Ops") or lines.get("XLA Modules") or []
-        busy = _union([_clip(ev.start_ns, ev.end_ns, w0, w1) for ev in ops
-                       if ev.end_ns > w0 and ev.start_ns < w1])
+        busy = _union([(s if s > w0 else w0, e if e < w1 else w1)
+                       for _, s, e in ops if e > w0 and s < w1])
         busy_total += sum(e - s for s, e in busy)
-        for name, t in _self_times(lines.get("XLA Ops", []), w0, w1):
+        for ev, t in self_times:
             if t > 0:
-                op_s[_op_name(name)] += t
+                name = ev.name
+                op = op_names.get(name)
+                if op is None:         # an op runs once a layer and step
+                    op = op_names[name] = _op_name(name)
+                op_s[op] += t
         for ev in lines.get("XLA Modules", []):
             s, e = _clip(ev.start_ns, ev.end_ns, w0, w1)
             if e <= s:
@@ -133,13 +206,7 @@ def reduce(pd, execs: dict = None) -> dict:
                     exec_s[kind] += e - s
                     exec_n[kind] += 1
         edges = [w0] + [x for iv in busy for x in iv] + [w1]
-        for s, e in zip(edges[0::2], edges[1::2]):
-            if e > s:
-                mid = (s + e) / 2
-                open_ = [sp for sp in inner if sp[1] <= mid <= sp[2]]
-                name = (min(open_, key=lambda sp: sp[2] - sp[1])[0]
-                        if open_ else "outside")
-                gaps.append((name, e - s))
+        gaps += [(s, e) for s, e in zip(edges[0::2], edges[1::2]) if e > s]
     n = len(devices)
     ns = 1e-9
     return {
@@ -150,12 +217,12 @@ def reduce(pd, execs: dict = None) -> dict:
                  for k in execs},
         "device_ops": [[name, t / n * ns] for name, t in sorted(
             op_s.items(), key=lambda kv: (-kv[1], kv[0]))[:TOP]],
-        "idle_gaps": [[name, t * ns] for name, t in sorted(
-            gaps, key=lambda g: -g[1])[:TOP]],
+        "idle_gaps": [[_gap_name(inner, s, e), (e - s) * ns] for s, e in
+                      sorted(gaps, key=lambda g: -(g[1] - g[0]))[:TOP]],
         "modules": {k: t / n * ns for k, t in modules.items()},
     }
 
 
 def reduce_dir(trace_dir: str, execs: dict = None) -> dict:
-    from jax.profiler import ProfileData
-    return reduce(ProfileData.from_file(find_xplane(trace_dir)), execs)
+    path = find_xplane(trace_dir)
+    return reduce(load(path, os.stat(path).st_mtime_ns), execs)
