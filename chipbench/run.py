@@ -2,6 +2,7 @@
 
   python chipbench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
   python chipbench/run.py --plan        # CPU: each cell's prefill and decode shapes
+  python chipbench/run.py --plan --root <copy>   # the cells of another benchmark
 
 A run, in order:
 
@@ -407,8 +408,13 @@ def main(argv=None):
     ap.add_argument("--seconds", type=float, default=10.0)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--plan", action="store_true")
+    ap.add_argument("--root", help="with --plan: plan the cells of the "
+                    "benchmark at ROOT (a copy of BENCHMARK.json and "
+                    "chipbench/ that holds a candidate cell)")
     args = ap.parse_args(argv)
-    bench = spec.load_benchmark()
+    if args.root and not args.plan:
+        ap.error("--root is read by --plan only")
+    bench = spec.use_root(args.root) if args.root else spec.load_benchmark()
     if args.plan:
         for p in plan(bench):
             print(json.dumps(p), flush=True)

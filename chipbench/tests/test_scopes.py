@@ -254,13 +254,15 @@ def test_read_dir_reads_each_trace_file_once(monkeypatch, tmp_path):
     assert len(calls) == 2
 
 
-def test_new_metrics_are_listed_with_their_cells():
+def test_new_metrics_cover_every_cell():
+    """The six read names that every cell's program gives, so they carry
+    no ``workloads`` list: a cell added later reports them too."""
     bench = spec.load_benchmark()
     listed = {m["name"]: m for m in bench["per_layer"]}
-    cells = [w["name"] for w in bench["workloads"]]
     for name in READS:
         assert listed[name]["moves"] == "output_tok_s"
-        assert listed[name]["workloads"] == cells
+        assert "workloads" not in listed[name]
+        assert name in spec.EVERY_CELL
 
 
 def mesh_xspace():
