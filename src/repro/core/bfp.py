@@ -241,6 +241,20 @@ def unpack_int4(packed: jax.Array, axis: int = -1) -> jax.Array:
     return jnp.moveaxis(out, -1, axis)
 
 
+def int4_pairs(packed: jax.Array) -> jax.Array:
+    """The signed values of packed 4-bit pairs as ``int4``, shape
+    ``packed.shape + (2,)``: [..., 0] the low nibble (the even index of
+    ``pack_int4``), [..., 1] the high one.  For a contraction that reads
+    the pair axis in place instead of interleaving it.
+
+    Split by shifts, written as ``int4`` (the packed bytes' size): a
+    ``bitcast_convert_type`` of the bytes to int4 pairs gives wrong
+    products on a TPU v5e."""
+    lo = (jnp.left_shift(packed, 4) >> 4).astype(jnp.int4)
+    hi = (packed >> 4).astype(jnp.int4)
+    return jnp.stack([lo, hi], axis=-1)
+
+
 # ---------------------------------------------------------------------------
 # Site-specific helpers (paper Fig. 6a grouping directions)
 # ---------------------------------------------------------------------------
@@ -294,7 +308,8 @@ def kv_cache_reduction(mantissa_bits: int, group_size: int = 32,
 
 __all__ = [
     "BfpConfig", "bfp_fake_quant", "bfp_quantize", "bfp_dequantize",
-    "pack_int4", "unpack_int4", "quant_per_token", "quant_v_cache",
+    "pack_int4", "unpack_int4", "int4_pairs", "quant_per_token",
+    "quant_v_cache",
     "quantization_error", "kv_cache_reduction", "EXP_MIN", "EXP_MAX",
     "DEFAULT_GROUP_SIZE", "DEFAULT_MANTISSA_BITS",
 ]

@@ -426,12 +426,76 @@ def append_token(cache: AsymKVCache, k_new: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# The recent window in position order (shared by gather_kv and the packed
+# decode attention)
+# ---------------------------------------------------------------------------
+
+def k_window(cache: AsymKVCache, dtype=jnp.float32):
+    """The 8-bit local K ring, dequantized and rolled into position order.
+
+    Returns (k_win, w0, live): k_win (B, LOCAL, n_kv, hd) holds positions
+    [w0, w0+LOCAL) with w0 = clip(L-LOCAL, INIT, max_seq-LOCAL); live
+    (LOCAL,) marks the positions below L, the ones the ring holds (the
+    rest keep the bulk's contents in position order)."""
+    L = cache.length
+    k_local = _dq_k(cache.k_local_mant, cache.k_local_exp, 8, dtype)
+    # position p lives at ring slot (p-32)%64, so position order is the
+    # ring rolled by -(w0-32): O(64) work
+    w0 = jnp.clip(L - LOCAL_TOKENS, INIT_TOKENS,
+                  cache.max_seq - LOCAL_TOKENS)
+    k_win = jax.lax.dynamic_slice_in_dim(
+        jnp.concatenate([k_local, k_local], axis=1),
+        (w0 - INIT_TOKENS) % LOCAL_TOKENS, LOCAL_TOKENS, axis=1)
+    live = w0 + jnp.arange(LOCAL_TOKENS) < L
+    return k_win, w0, live
+
+
+def v_window(cache: AsymKVCache, dtype=jnp.float32):
+    """The recent V window: the complete ring groups {cg-2, cg-1} and the
+    residual group cg (cg = L//32), three groups in position order.
+
+    Returns (v_win, g0, mask): v_win (B, 96, n_kv, hd) holds positions
+    [32·g0, 32·g0+96); mask (96,) marks the positions it owns — a
+    complete ring group, or a residual token below L.  The residual is
+    re-converted at its current size (incremental grouping: padded
+    residual slots are zero and never raise the shared max-exponent).
+    Unmasked positions keep the init or bulk contents in position order.
+    """
+    L = cache.length
+    cg = L // GROUP
+    r = L % GROUP
+    v_local = _dq_v_group(cache.v_local_mant, cache.v_local_exp, 8, dtype)
+    resid_valid = jnp.arange(GROUP) < r
+    resid = jnp.where(resid_valid[None, :, None, None],
+                      cache.v_resid.astype(jnp.float32), 0.0)
+    resid_q = bfp.bfp_fake_quant(resid, GROUP, 8, "trunc",
+                                 axis=1).astype(dtype)
+    n_win = V_LOCAL_GROUPS + 1
+    g0 = jnp.clip((cg - V_LOCAL_GROUPS) * GROUP, 0,
+                  cache.max_seq - n_win * GROUP) // GROUP
+    parts, masks = [], []
+    for i in range(n_win):
+        gi = g0 + i
+        from_ring = jnp.where(gi % V_LOCAL_GROUPS == 0,
+                              v_local[:, :GROUP], v_local[:, GROUP:])
+        parts.append(jnp.where(gi == cg, resid_q, from_ring))
+        is_local = (gi >= 1) & (gi >= cg - V_LOCAL_GROUPS) & (gi < cg)
+        masks.append(jnp.where(gi == cg, resid_valid,
+                               jnp.broadcast_to(is_local, (GROUP,))))
+    return jnp.concatenate(parts, axis=1), g0, jnp.concatenate(masks)
+
+
+# ---------------------------------------------------------------------------
 # Gather: dequantize to positionally-ordered (B, S_cap, n_kv, hd) + mask
 # ---------------------------------------------------------------------------
 
 def gather_kv(cache: AsymKVCache, dtype=jnp.float32, *,
               legacy: bool = False):
     """Dequantize the full cache into position order.
+
+    The served decode does not call it: ``attention_decode_packed``
+    contracts the packed bulk in place.  It is the reference the decode
+    tests compare with, and the cache read of the ``seq_shard`` decode.
 
     ``legacy=True`` dispatches to the scatter/`.at[].set` formulation (the
     reference of ``tests/test_kvcache.py``) — bit-identical values.
@@ -467,24 +531,13 @@ def gather_kv(cache: AsymKVCache, dtype=jnp.float32, *,
     k_bulk = _dq_k(bfp.unpack_int4(cache.k_bulk_mant, axis=-1),
                    cache.k_bulk_exp, 4, dtype)
     k = jnp.concatenate([k_init, k_bulk], axis=1)
-    k_local = _dq_k(cache.k_local_mant, cache.k_local_exp, 8, dtype)
-    # window [w0, w0+64) with w0 = max(L-64, 32): position p lives at ring
-    # slot (p-32)%64, so position order is the ring rolled by -(w0-32)
-    w0 = jnp.clip(L - LOCAL_TOKENS, INIT_TOKENS, S - LOCAL_TOKENS)
-    k_win = jax.lax.dynamic_slice_in_dim(        # ring rolled into position
-        jnp.concatenate([k_local, k_local], axis=1),  # order, O(64) work
-        (w0 - INIT_TOKENS) % LOCAL_TOKENS, LOCAL_TOKENS, axis=1)
-    w_pos = w0 + jnp.arange(LOCAL_TOKENS)
+    k_win, w0, live = k_window(cache, dtype)
     base = jax.lax.dynamic_slice_in_dim(k, w0, LOCAL_TOKENS, axis=1)
-    merged = jnp.where((w_pos < L)[None, :, None, None], k_win, base)
+    merged = jnp.where(live[None, :, None, None], k_win, base)
     k = jax.lax.dynamic_update_slice_in_dim(k, merged, w0, axis=1)
 
-    # --- V: [init | bulk | zero tail] in position order + a 3-group
-    # window covering the complete ring groups {cg-2, cg-1} and the
-    # residual group cg (incremental grouping: padded residual slots are
-    # zero and never raise the shared max-exponent) ---
-    cg = L // GROUP
-    r = L % GROUP
+    # --- V: [init | bulk | zero tail] in position order + the recent
+    # window of v_window ---
     v_init = _dq_v_group(cache.v_init_mant, cache.v_init_exp, 8, dtype)
     vb_unpacked = bfp.unpack_int4(cache.v_bulk_mant, axis=1)
     n_bulk_groups = cache.v_bulk_exp.shape[1]
@@ -493,27 +546,8 @@ def gather_kv(cache: AsymKVCache, dtype=jnp.float32, *,
         cache.v_bulk_exp[:, : n_bulk_groups - 1], 4, dtype)
     v = jnp.concatenate(
         [v_init, v_bulk, jnp.zeros((B, GROUP, H, D), dtype)], axis=1)
-    v_local = _dq_v_group(cache.v_local_mant, cache.v_local_exp, 8, dtype)
-    resid_valid = jnp.arange(GROUP) < r
-    resid = jnp.where(resid_valid[None, :, None, None],
-                      cache.v_resid.astype(jnp.float32), 0.0)
-    resid_q = bfp.bfp_fake_quant(resid, GROUP, 8, "trunc",
-                                 axis=1).astype(dtype)
-    n_win = V_LOCAL_GROUPS + 1
-    g0 = jnp.clip((cg - V_LOCAL_GROUPS) * GROUP, 0,
-                  S - n_win * GROUP) // GROUP
-    parts, masks = [], []
-    for i in range(n_win):
-        gi = g0 + i
-        from_ring = jnp.where(gi % V_LOCAL_GROUPS == 0,
-                              v_local[:, :GROUP], v_local[:, GROUP:])
-        parts.append(jnp.where(gi == cg, resid_q, from_ring))
-        is_local = (gi >= 1) & (gi >= cg - V_LOCAL_GROUPS) & (gi < cg)
-        masks.append(jnp.where(gi == cg, resid_valid,
-                               jnp.broadcast_to(is_local, (GROUP,))))
-    v_win = jnp.concatenate(parts, axis=1)          # (B, 96, H, D)
-    v_mask = jnp.concatenate(masks)                 # (96,)
-    base = jax.lax.dynamic_slice_in_dim(v, g0 * GROUP, n_win * GROUP,
+    v_win, g0, v_mask = v_window(cache, dtype)
+    base = jax.lax.dynamic_slice_in_dim(v, g0 * GROUP, v_win.shape[1],
                                         axis=1)
     merged = jnp.where(v_mask[None, :, None, None], v_win, base)
     v = jax.lax.dynamic_update_slice_in_dim(v, merged, g0 * GROUP, axis=1)
@@ -677,6 +711,7 @@ def fp16_cache_bytes(batch: int, n_kv: int, head_dim: int,
 
 
 __all__ = ["AsymKVCache", "init_cache", "prefill_cache", "append_token",
-           "gather_kv", "fake_quant_kv", "cache_bytes", "fp16_cache_bytes",
+           "gather_kv", "k_window", "v_window", "fake_quant_kv",
+           "cache_bytes", "fp16_cache_bytes",
            "predicated_write",
            "INIT_TOKENS", "LOCAL_TOKENS", "GROUP", "V_LOCAL_GROUPS"]

@@ -16,8 +16,9 @@ Three execution paths:
      that moment (8-bit if t' < 32 or t' >= t - 64, else 4-bit).  Used by
      the accuracy benchmarks (Table I/II analogues).  Costs 2x scores.
   3. ``attention_decode_packed`` — one-token decode against the packed
-     ``AsymKVCache`` (dequantize-and-attend; the Pallas kernel fuses this
-     on TPU).
+     ``AsymKVCache``: the 4-bit bulk mantissas are contracted in place and
+     their shared exponents applied after the dot (a Pallas kernel form
+     exists too, ``use_pallas=True``).
 """
 from __future__ import annotations
 
@@ -457,6 +458,96 @@ def _decode_packed_pallas(q: jax.Array, cache: kvcache.AsymKVCache, *,
     return out.reshape(B, 1, H, hd)
 
 
+def _decode_packed_xla(q: jax.Array, cache: kvcache.AsymKVCache, *,
+                       logit_cap: float, quant: Optional[QuantConfig],
+                       extra_invalid_prefix: Optional[jax.Array]
+                       ) -> jax.Array:
+    """One-token decode on the packed cache without a float copy of it.
+
+    q: (B,1,H,hd), already BFP-quantized.  The result equals attention
+    over ``kvcache.gather_kv``'s position-ordered cache:
+
+    * scores (B, Hkv, rep, max_seq) in position order: [init | bulk], and
+      ``kvcache.k_window``'s rolled ring overlaid where its positions are
+      live.  The 4-bit K bulk is contracted in place: the nibble split is
+      folded into the query (low nibble with the even channels, high with
+      the odd ones), each 32-channel group gives a float32 partial, and
+      its shared exponent scales the partial after the dot;
+    * masks, softcap, softmax and P's BFP groups along positions as the
+      gather path has them;
+    * P·V as the sum of the three parts ``gather_kv`` overlays: the init
+      group, the 4-bit bulk groups and ``kvcache.v_window``'s recent
+      window.  P is zeroed where the window owns the position before the
+      bulk contraction; the bulk's token pairs meet P's even and odd
+      positions, and each 32-token group's partial is scaled by its
+      exponents after the dot.
+
+    q and P enter the dots in q's dtype (bf16 when serving, where BFP8 q
+    and P are exact), the mantissas are exact integers there, the dots
+    accumulate in float32 and the 2^e steps are applied in float32,
+    exactly.  So this differs from a dot over the dequantized cache only
+    in the order of the float32 sums.
+    """
+    G, INIT = kvcache.GROUP, kvcache.INIT_TOKENS
+    B, _, H, hd = q.shape
+    _, T, Hkv, _ = cache.k_bulk_mant.shape      # T bulk K slots
+    rep = H // Hkv
+    ng = hd // G
+    nb = cache.v_bulk_exp.shape[1] - 1          # bulk V groups 1 .. nb
+    S = cache.max_seq
+    L = cache.length
+    dt = q.dtype
+    f32 = jnp.float32
+    with jax.named_scope("kv_gather"):
+        k_init = kvcache._dq_k(cache.k_init_mant, cache.k_init_exp, 8, dt)
+        k_win, w0, k_live = kvcache.k_window(cache, dt)
+        v_init = kvcache._dq_v_group(cache.v_init_mant, cache.v_init_exp,
+                                     8, dt)
+        v_win, g0, v_mask = kvcache.v_window(cache, dt)
+        # 4-bit mantissas: a step of 2^(e-2)
+        k_nib = bfp.int4_pairs(cache.k_bulk_mant.reshape(
+            B, T, Hkv, ng, G // 2))             # (B, T, Hkv, ng, 16, 2)
+        k_step = bfp.pow2(cache.k_bulk_exp.astype(f32) - 2.0)
+        v_nib = bfp.int4_pairs(cache.v_bulk_mant[:, :nb * G // 2].reshape(
+            B, nb, G // 2, Hkv, hd))            # (B, nb, 16, Hkv, hd, 2)
+        v_step = bfp.pow2(cache.v_bulk_exp[:, :nb].astype(f32) - 2.0)
+    with jax.named_scope("attend"):
+        qg = q[:, 0].reshape(B, Hkv, rep, hd)
+        qk = partial(jnp.einsum, "bhrd,bthd->bhrt",
+                     preferred_element_type=f32)
+        part = jnp.einsum("bhrgcn,bthgcn->bhrtg",
+                          qg.reshape(B, Hkv, rep, ng, G // 2, 2),
+                          k_nib.astype(dt), preferred_element_type=f32)
+        s_bulk = jnp.sum(part * jnp.moveaxis(k_step, 1, 2)[:, :, None],
+                         axis=-1)
+        s = jnp.concatenate([qk(qg, k_init), s_bulk], axis=-1)
+        base = jax.lax.dynamic_slice_in_dim(s, w0, kvcache.LOCAL_TOKENS,
+                                            axis=-1)
+        s = jax.lax.dynamic_update_slice_in_dim(
+            s, jnp.where(k_live, qk(qg, k_win), base), w0, axis=-1)
+        s = s / jnp.sqrt(float(hd))                  # (B, Hkv, rep, S)
+        pos = jnp.arange(S)
+        m = (pos < L)[None, :]
+        if extra_invalid_prefix is not None:
+            m = m & (pos[None, :] >= extra_invalid_prefix[:, None])
+        p = _masked_softmax(s, m[:, None, None], logit_cap)
+        p = _quant_p(p, quant)
+
+        pv = partial(jnp.einsum, "bhrt,bthd->bhrd",
+                     preferred_element_type=f32)
+        p_w = jax.lax.dynamic_slice_in_dim(p, G * g0, v_win.shape[1],
+                                           axis=-1)
+        p = jax.lax.dynamic_update_slice_in_dim(
+            p, jnp.where(v_mask, 0.0, p_w), G * g0, axis=-1)
+        p_b = p[..., INIT:INIT + nb * G].reshape(B, Hkv, rep, nb, G // 2, 2)
+        part = jnp.einsum("bhrjin,bjihdn->bhrjd", p_b.astype(dt),
+                          v_nib.astype(dt), preferred_element_type=f32)
+        o = (jnp.sum(part * jnp.moveaxis(v_step, 1, 2)[:, :, None], axis=3)
+             + pv(p[..., :G].astype(dt), v_init)
+             + pv(jnp.where(v_mask, p_w, 0.0).astype(dt), v_win))
+        return o.reshape(B, 1, H, hd)
+
+
 def attention_decode_packed(q: jax.Array, cache: kvcache.AsymKVCache, *,
                             logit_cap: float = 0.0,
                             quant: Optional[QuantConfig] = None,
@@ -481,10 +572,12 @@ def attention_decode_packed(q: jax.Array, cache: kvcache.AsymKVCache, *,
     kernels on both forms (DESIGN.md §2), so ``quant.quant_attention``
     P-quantization is not applied there.
 
-    The default XLA path dequantizes the cache to bf16 (mantissas <= 8
-    bits are exactly representable; the 2^e scales are exact) — halves
-    decode HBM traffic vs f32 (§Perf iteration 3); scores still
-    accumulate in f32.
+    The default XLA path (:func:`_decode_packed_xla`) attends on the
+    packed cache itself: the 4-bit bulk mantissas are contracted in place
+    and their shared exponents applied after the dot, so no float copy of
+    the cache is built.  ``seq_shard=True`` (dry-run only) dequantizes the
+    cache to bf16 through ``kvcache.gather_kv`` and keeps head_dim
+    sharded through the contractions.
     """
     hd = q.shape[-1]
     if use_pallas and not seq_shard:
@@ -497,6 +590,9 @@ def attention_decode_packed(q: jax.Array, cache: kvcache.AsymKVCache, *,
                 interpret=interpret)
     with jax.named_scope("attend"):
         q = _quant_qk(q, quant)
+    if not seq_shard:
+        return _decode_packed_xla(q, cache, logit_cap=logit_cap, quant=quant,
+                                  extra_invalid_prefix=extra_invalid_prefix)
     with jax.named_scope("kv_gather"):
         # gather in f32 and cast once: identical values (the dequants
         # compute in f32 either way; cast commutes with the pure data
@@ -505,16 +601,15 @@ def attention_decode_packed(q: jax.Array, cache: kvcache.AsymKVCache, *,
         k, v, valid = kvcache.gather_kv(cache, dtype=jnp.float32)
         k, v = k.astype(jnp.bfloat16), v.astype(jnp.bfloat16)
     with jax.named_scope("attend"):
-        if seq_shard:
-            # keep head_dim sharded through the QK contraction: partial
-            # score rows all-reduce (~40 MiB) instead of all-gathering the
-            # entire dequantized K cache (~1 GiB/layer measured; §Perf
-            # iteration 3)
-            from jax.sharding import PartitionSpec as P
-            wsc = jax.lax.with_sharding_constraint
-            k = wsc(k, P(dp_axes, None, None, "model"))
-            v = wsc(v, P(dp_axes, None, None, "model"))
-            q = wsc(q, P(dp_axes, None, None, "model"))
+        # keep head_dim sharded through the QK contraction: partial
+        # score rows all-reduce (~40 MiB) instead of all-gathering the
+        # entire dequantized K cache (~1 GiB/layer measured; §Perf
+        # iteration 3)
+        from jax.sharding import PartitionSpec as P
+        wsc = jax.lax.with_sharding_constraint
+        k = wsc(k, P(dp_axes, None, None, "model"))
+        v = wsc(v, P(dp_axes, None, None, "model"))
+        q = wsc(q, P(dp_axes, None, None, "model"))
         scores = _group_heads(q, k) / jnp.sqrt(float(hd))  # (B,Hkv,rep,1,T)
         m = valid[None, :]
         if extra_invalid_prefix is not None:

@@ -165,15 +165,12 @@ def _packed_linear(x: jax.Array, qw: QuantizedWeight,
     half = qw.in_dim // ngroups // 2
     with jax.named_scope("weight_dequant"):
         # (G, g/2, out, 2) INT4 values, [..., 0] the low nibble (the even
-        # index along in), sign-extended by the shifts.  Through int4 the
-        # split fuses with the layer scan's weight slice and writes int4
-        # nibbles, which the dot reads; a split fused into the dot makes
-        # the scan copy each layer's packed weight first, slower on v5e.
-        # (A bitcast of the bytes to int4 pairs gives wrong products there.)
-        p = qw.packed.reshape(ngroups, half, out_dim)
-        lo = (jnp.left_shift(p, 4) >> 4).astype(jnp.int4)
-        hi = (p >> 4).astype(jnp.int4)
-        w = jnp.stack([lo, hi], axis=-1).astype(x.dtype)
+        # index along in).  Through int4 the split fuses with the layer
+        # scan's weight slice and writes int4 nibbles, which the dot reads;
+        # a split fused into the dot makes the scan copy each layer's
+        # packed weight first, slower on v5e.
+        w = bfp.int4_pairs(qw.packed.reshape(ngroups, half, out_dim)
+                           ).astype(x.dtype)
     with jax.named_scope("matmul"):
         xs = x.reshape(x.shape[:-1] + (ngroups, half, 2))
         part = jnp.einsum("...gkn,gkon->...go", xs, w,

@@ -199,7 +199,8 @@ def _attention(q, k, v, kind: str, cfg: ModelConfig,
         if kind == "attn":
             with jax.named_scope("kv_append"):
                 new_cache = kvcache.append_token(cache, k[:, 0], v[:, 0])
-            # splits its work into kv_gather and attend
+            # attends on the packed cache in place: region reads and the
+            # nibble split under kv_gather, dots and softmax under attend
             attn = attn_lib.attention_decode_packed(
                 q, new_cache, logit_cap=cfg.attn_logit_softcap, quant=quant,
                 extra_invalid_prefix=ctx.pad_prefix,

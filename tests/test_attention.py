@@ -1,5 +1,6 @@
 """Attention paths: flash vs dense, eval-quant semantics, decode parity."""
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,17 +113,20 @@ def test_decode_packed_matches_eval_quant_early():
 from repro.core import bfp, kvcache
 
 
-def _decode_ref_f32(q, cache, logit_cap=0.0, prefix=None):
-    """f32 gather-everything reference for the packed decode (the
-    production XLA path dequantizes to bf16; the Pallas path is f32)."""
+def _decode_ref_f32(q, cache, logit_cap=0.0, prefix=None, quant=None):
+    """f32 gather-everything reference for the packed decode: the cache
+    dequantized into position order by ``gather_kv``, then q's and P's BFP
+    (``quant``), masks and softmax as the decode applies them."""
     hd = q.shape[-1]
     k, v, valid = kvcache.gather_kv(cache, dtype=jnp.float32)
-    scores = A._group_heads(q.astype(jnp.float32), k) / jnp.sqrt(float(hd))
+    q = A._quant_qk(q.astype(jnp.float32), quant)
+    scores = A._group_heads(q, k) / jnp.sqrt(float(hd))
     m = valid[None, :]
     if prefix is not None:
         pos = jnp.arange(k.shape[1])[None, :]
         m = m & (pos >= prefix[:, None])
     p = A._masked_softmax(scores, m[:, None, None, None], logit_cap)
+    p = A._quant_p(p, quant)
     return A._apply_scores_v(p, v)
 
 
@@ -138,23 +142,28 @@ def _build_cache(B, Hkv, hd, max_seq, S_pre, n_append):
     return cache
 
 
-@pytest.mark.parametrize("S_pre,n_append,cap",
-                         [(128, 0, 0.0),    # bulk exactly at region edge
-                          (128, 5, 0.0),    # residual group active
-                          (256, 37, 0.0),   # deep bulk + residual
-                          (96, 0, 0.0),     # bulk empty, window ragged
-                          (64, 3, 0.0),     # local ring only
-                          (32, 1, 0.0),     # init + one token
-                          (256, 0, 30.0)])  # logit softcap
+# (S_pre, n_append, logit_cap): the cache regions a decode step meets
+_DECODE_REGION_CASES = [(128, 0, 0.0),    # bulk exactly at region edge
+                        (128, 5, 0.0),    # residual group active
+                        (256, 37, 0.0),   # deep bulk + residual
+                        (96, 0, 0.0),     # bulk empty, window ragged
+                        (64, 3, 0.0),     # local ring only
+                        (32, 1, 0.0),     # init + one token
+                        (256, 0, 30.0)]   # logit softcap
+
+
+def _rel_err(out, ref):
+    return float(jnp.abs(out - ref).max()) / float(jnp.abs(ref).max())
+
+
+@pytest.mark.parametrize("S_pre,n_append,cap", _DECODE_REGION_CASES)
 def test_decode_packed_pallas_matches_f32_reference(S_pre, n_append, cap):
     B, Hkv, H, hd = 2, 2, 4, 64
     cache = _build_cache(B, Hkv, hd, 512, S_pre, n_append)
     q = jnp.asarray(RNG.normal(size=(B, 1, H, hd)).astype(np.float32))
     out_p = A.attention_decode_packed(q, cache, logit_cap=cap,
                                       use_pallas=True)
-    out_r = _decode_ref_f32(q, cache, cap)
-    rel = (float(jnp.abs(out_p - out_r).max())
-           / float(jnp.abs(out_r).max()))
+    rel = _rel_err(out_p, _decode_ref_f32(q, cache, cap))
     assert rel < 1e-5, rel
 
 
@@ -165,21 +174,93 @@ def test_decode_packed_pallas_left_pad_prefix():
     prefix = jnp.asarray([0, 40], jnp.int32)
     out_p = A.attention_decode_packed(q, cache, extra_invalid_prefix=prefix,
                                       use_pallas=True)
-    out_r = _decode_ref_f32(q, cache, prefix=prefix)
-    rel = (float(jnp.abs(out_p - out_r).max())
-           / float(jnp.abs(out_r).max()))
+    rel = _rel_err(out_p, _decode_ref_f32(q, cache, prefix=prefix))
     assert rel < 1e-5, rel
 
 
 def test_decode_packed_pallas_close_to_xla_path():
-    """The bf16 XLA path and the f32 Pallas path agree to bf16 P
-    resolution."""
+    """The XLA path (dots in q's dtype, bf16 when serving) and the f32
+    Pallas path agree to bf16 P resolution."""
     B, Hkv, H, hd = 2, 2, 4, 64
     cache = _build_cache(B, Hkv, hd, 512, 256, 10)
     q = jnp.asarray(RNG.normal(size=(B, 1, H, hd)).astype(np.float32))
     out_p = A.attention_decode_packed(q, cache, use_pallas=True)
     out_x = A.attention_decode_packed(q, cache, use_pallas=False)
     assert float(jnp.abs(out_p - out_x).max()) < 0.05
+
+
+# The default XLA decode (use_pallas=False) contracts the packed cache in
+# place.  Its dots take q and P in q's dtype: float32 here, so it differs
+# from the float32 gather reference only in the order of float32 sums
+# (the mantissas are exact integers, the 2^e steps exact), and the
+# Pallas tests' relative 1e-5 applies.  A wrong region, mask or exponent
+# is off by ~1e-2.
+@pytest.mark.parametrize("Hkv,H", [(2, 2), (2, 6)], ids=["mha", "gqa"])
+@pytest.mark.parametrize("S_pre,n_append,cap", _DECODE_REGION_CASES)
+def test_decode_packed_xla_matches_f32_reference(S_pre, n_append, cap,
+                                                 Hkv, H):
+    B, hd = 2, 64
+    cache = _build_cache(B, Hkv, hd, 512, S_pre, n_append)
+    q = jnp.asarray(RNG.normal(size=(B, 1, H, hd)).astype(np.float32))
+    out = jax.jit(lambda q, c: A.attention_decode_packed(
+        q, c, logit_cap=cap))(q, cache)
+    assert _rel_err(out, _decode_ref_f32(q, cache, cap)) < 1e-5
+
+
+@pytest.mark.parametrize("Hkv,H", [(2, 2), (2, 4)], ids=["mha", "gqa"])
+def test_decode_packed_xla_left_pad_prefix(Hkv, H):
+    B, hd = 2, 64
+    cache = _build_cache(B, Hkv, hd, 512, 192, 70)
+    q = jnp.asarray(RNG.normal(size=(B, 1, H, hd)).astype(np.float32))
+    prefix = jnp.asarray([0, 40], jnp.int32)
+    out = A.attention_decode_packed(q, cache, extra_invalid_prefix=prefix)
+    assert _rel_err(out, _decode_ref_f32(q, cache, prefix=prefix)) < 1e-5
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_decode_packed_xla_quantized_scores(dtype):
+    """With quant_attention, q and P are BFP8 on both sides: P's groups
+    of 32 run along position order, as over the gathered cache.  BFP8
+    values are exact in bf16, so the served bf16 dots (P cast to q's
+    dtype) keep the float32 reference's tolerance too."""
+    B, Hkv, H, hd = 2, 2, 4, 64
+    quant = harmonia(4)
+    assert quant.quant_attention
+    cache = _build_cache(B, Hkv, hd, 512, 256, 10)
+    q = jnp.asarray(RNG.normal(size=(B, 1, H, hd)).astype(np.float32)
+                    ).astype(dtype)
+    out = A.attention_decode_packed(q, cache, quant=quant)
+    ref = _decode_ref_f32(q, cache, quant=quant)
+    assert _rel_err(out, ref) < 1e-5
+    # P's BFP8 acts: dropping it moves the output far beyond the tolerance
+    assert _rel_err(out, _decode_ref_f32(q, cache)) > 1e-4
+
+
+def _largest_float_array(hlo_text):
+    sizes = [int(np.prod([int(d) for d in m.group(2).split(",")]))
+             for m in re.finditer(r"\b(f64|f32|bf16|f16)\[([0-9,]+)\]",
+                                  hlo_text)]
+    return max(sizes, default=0)
+
+
+def test_decode_packed_xla_builds_no_float_cache():
+    """The optimized decode holds no float array as large as the cache
+    dequantized into position order (B·max_seq·Hkv·hd); the gather
+    reference does."""
+    B, S, Hkv, H, hd = 2, 512, 2, 4, 64
+    cache = jax.eval_shape(lambda: kvcache.init_cache(B, Hkv, hd, S))
+    q = jax.ShapeDtypeStruct((B, 1, H, hd), jnp.bfloat16)
+    prefix = jax.ShapeDtypeStruct((B,), jnp.int32)
+    whole = B * S * Hkv * hd
+
+    def largest(fn):
+        return _largest_float_array(
+            jax.jit(fn).lower(q, cache, prefix).compile().as_text())
+
+    assert largest(lambda q, c, p: A.attention_decode_packed(
+        q, c, quant=harmonia(4), extra_invalid_prefix=p)) < whole
+    assert largest(lambda q, c, p: _decode_ref_f32(q, c, prefix=p)) >= whole
 
 
 def test_prefill_pallas_matches_fakequant_forward():
